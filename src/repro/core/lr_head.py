@@ -42,8 +42,10 @@ def init_head(key, n_classes: int, feat_dim: int, scale: float = 0.0) -> jax.Arr
 
 
 def probs(w: jax.Array, Xa: jax.Array) -> jax.Array:
-    """softmax(W x̃) for augmented features Xa [N, d+1] -> [N, C]."""
-    z = Xa @ w.T
+    """softmax(W x̃) for augmented features Xa [N, d+1] -> [N, C]. The logits
+    contract at full f32 precision (a TPU's default is bf16 passes), as the
+    head's Pallas kernels do, so every backend feeds them the same P."""
+    z = jnp.dot(Xa, w.T, precision=jax.lax.Precision.HIGHEST)
     return jax.nn.softmax(z.astype(jnp.float32), axis=-1)
 
 

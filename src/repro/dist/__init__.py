@@ -1,7 +1,7 @@
 """repro.dist — distribution substrate: sharding rules, elastic restore,
 fault tolerance.
 
-  compat    — mesh constructors that work across jax versions
+  compat    — the one mesh / abstract-mesh / shard_map constructor set
   sharding  — logical-axis rulebook (make_resolver / resolve_axes / batch_axes)
   elastic   — elastic_restore: checkpoint restore onto a *different* mesh
   fault     — Heartbeat, StragglerMonitor, retry_step

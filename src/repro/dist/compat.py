@@ -1,65 +1,32 @@
-"""Mesh constructors that work across jax versions.
+"""Mesh and shard_map constructors, in one place.
 
-Newer jax (>= 0.5) grew `axis_types=` on `jax.make_mesh` and changed
-`AbstractMesh` to take positional (sizes, names); 0.4.x predates both.
-Everything in this repo (and the tests) builds meshes through these two
-helpers so the sharding rulebook is exercised identically on either API.
+Everything in this repo (and the tests) builds meshes and shard_maps
+through these three helpers, so the sharding rulebook is exercised one way:
+mesh axes are `Auto`-typed, and shard_map runs without the replication
+check (the sharded backend's bodies contain jit'd Pallas calls the checker
+cannot see through). Written for the installed jax 0.9.
 """
 from __future__ import annotations
 
 from typing import Sequence
 
 import jax
+from jax.sharding import AbstractMesh, AxisType
 
 
 def make_compat_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """A concrete device mesh with Auto axis types where supported."""
-    import inspect
-
-    axis_shapes = tuple(axis_shapes)
-    axis_names = tuple(axis_names)
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    # probe the signature rather than try/except TypeError, which would also
-    # swallow unrelated TypeErrors raised from inside make_mesh
-    if axis_type is not None and "axis_types" in inspect.signature(jax.make_mesh).parameters:
-        return jax.make_mesh(
-            axis_shapes, axis_names,
-            axis_types=(axis_type.Auto,) * len(axis_names),
-        )
-    return jax.make_mesh(axis_shapes, axis_names)
+    """A concrete device mesh with Auto axis types."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """shard_map across jax versions: the function moved from
-    jax.experimental.shard_map to jax.shard_map (~0.6), and the replication
-    check kwarg was renamed check_rep -> check_vma. The check is disabled
-    either way (the sharded backend's bodies contain jit'd Pallas calls the
-    checker cannot see through)."""
-    import inspect
-
-    try:
-        from jax import shard_map as sm  # jax >= 0.6
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    params = inspect.signature(sm).parameters
-    if "check_vma" in params:
-        kwargs["check_vma"] = False
-    elif "check_rep" in params:
-        kwargs["check_rep"] = False
-    return sm(fn, **kwargs)
+    """`jax.shard_map` with the replication check off."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def abstract_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
     """An AbstractMesh (no devices) — resolver logic against production
     shapes without needing the hardware."""
-    from jax.sharding import AbstractMesh
-
-    axis_shapes = tuple(axis_shapes)
-    axis_names = tuple(axis_names)
-    try:
-        return AbstractMesh(axis_shapes, axis_names)
-    except TypeError:
-        # jax 0.4.x: AbstractMesh(((name, size), ...))
-        return AbstractMesh(tuple(zip(axis_names, axis_shapes)))
+    return AbstractMesh(tuple(axis_shapes), tuple(axis_names))

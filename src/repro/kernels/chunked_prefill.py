@@ -40,7 +40,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.flash_attention import NEG_INF, _kv_block_step
+from repro.kernels.flash_attention import (NEG_INF, _kv_block_step,
+                                          position_blocks)
 
 
 def chunk_blocks(chunk: int, block_k: int) -> int:
@@ -64,8 +65,8 @@ def _chunk_kernel(
     def _resume():
         # resume the fold: carry-in arrays replace the NEG_INF/0/0 init of
         # the full kernel (the first chunk's carry-in IS that neutral init)
-        m_scr[...] = m_in_ref[0, 0]
-        l_scr[...] = l_in_ref[0, 0]
+        m_scr[...] = m_in_ref[0, 0, :, 0]
+        l_scr[...] = l_in_ref[0, 0, :, 0]
         acc_scr[...] = acc_in_ref[0, 0]
 
     q = q_ref[0, 0].astype(jnp.float32)  # [BQ, D]
@@ -73,7 +74,7 @@ def _chunk_kernel(
     v = v_ref[0, 0].astype(jnp.float32)  # [BK, D]
     m_new, l_new, acc = _kv_block_step(
         (m_scr[...], l_scr[...], acc_scr[...]), q, k, v,
-        qpos_ref[...], kpos_ref[...],
+        qpos_ref[:, 0], kpos_ref[0, :],
         scale=scale, causal=causal, window=window, softcap=softcap,
     )
     m_scr[...] = m_new
@@ -82,15 +83,16 @@ def _chunk_kernel(
 
     @pl.when(ki == nk - 1)
     def _emit():
-        m_out_ref[0, 0] = m_new
-        l_out_ref[0, 0] = l_new
+        m_out_ref[0, 0] = m_new[:, None]
+        l_out_ref[0, 0] = l_new[:, None]
         acc_out_ref[0, 0] = acc
 
 
 def _chunk_call(q, k, v, qpos, kpos, m, l, acc, *, scale, causal, window,
                 softcap, block_q, block_k, interpret):
     """One resumable chunk of the flash fold: k/v/kpos are ONE chunk's
-    slice; (m, l, acc) carry in as arrays and out as updated arrays."""
+    slice; (m, l, acc) carry in as arrays and out as updated arrays (m, l
+    as [B, Hq, Sq, 1] columns, blocked (block_q, 1) like qpos)."""
     B, Hq, Sq, D = q.shape
     Hkv, Skv = k.shape[1], k.shape[2]
     G = Hq // Hkv
@@ -100,14 +102,14 @@ def _chunk_call(q, k, v, qpos, kpos, m, l, acc, *, scale, causal, window,
         softcap=float(softcap), nk=nk,
     )
     grid = (B, Hq, nq, nk)
-    carry2 = pl.BlockSpec((1, 1, block_q), lambda b, h, qi, ki: (b, h, qi))
+    carry2 = pl.BlockSpec((1, 1, block_q, 1), lambda b, h, qi, ki: (b, h, qi, 0))
     carry3 = pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0))
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_q,), lambda b, h, qi, ki: (qi,)),  # qpos
-            pl.BlockSpec((block_k,), lambda b, h, qi, ki: (ki,)),  # kpos
+            pl.BlockSpec((block_q, 1), lambda b, h, qi, ki: (qi, 0)),  # qpos
+            pl.BlockSpec((1, block_k), lambda b, h, qi, ki: (0, ki)),  # kpos
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki: (b, h // G, ki, 0)),
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki: (b, h // G, ki, 0)),
@@ -115,8 +117,8 @@ def _chunk_call(q, k, v, qpos, kpos, m, l, acc, *, scale, causal, window,
         ],
         out_specs=[carry2, carry2, carry3],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Hq, Sq), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hq, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hq, Sq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hq, Sq, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, Hq, Sq, D), jnp.float32),
         ],
         scratch_shapes=[
@@ -125,7 +127,7 @@ def _chunk_call(q, k, v, qpos, kpos, m, l, acc, *, scale, causal, window,
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
-    )(qpos, kpos, q, k, v, m, l, acc)
+    )(*position_blocks(qpos, kpos), q, k, v, m, l, acc)
 
 
 def chunked_prefill_partials_pallas(
@@ -153,8 +155,8 @@ def chunked_prefill_partials_pallas(
     assert Sq % block_q == 0 and Skv % block_k == 0, (Sq, Skv, block_q, block_k)
     c = chunk_blocks(chunk, block_k)
     scale = D**-0.5
-    m = jnp.full((B, Hq, Sq), NEG_INF, jnp.float32)
-    l = jnp.zeros((B, Hq, Sq), jnp.float32)
+    m = jnp.full((B, Hq, Sq, 1), NEG_INF, jnp.float32)
+    l = jnp.zeros((B, Hq, Sq, 1), jnp.float32)
     acc = jnp.zeros((B, Hq, Sq, D), jnp.float32)
     for start in range(0, Skv, c):
         stop = min(start + c, Skv)
@@ -168,7 +170,8 @@ def chunked_prefill_partials_pallas(
             scale=scale, causal=causal, window=window, softcap=softcap,
             block_q=block_q, block_k=block_k, interpret=interpret,
         )
-    return m[:, :, None, :], l[:, :, None, :], acc[:, :, None, :, :]
+    return (m.reshape(B, Hq, 1, Sq), l.reshape(B, Hq, 1, Sq),
+            acc[:, :, None, :, :])
 
 
 def chunked_prefill_partials_reference(

@@ -59,7 +59,7 @@ def _kernel(valid_ref, q_ref, k_ref, v_ref, o_ref, *, scale: float, softcap: flo
         q_ref[0, 0].astype(jnp.float32),
         k_ref[0, 0].astype(jnp.float32),
         v_ref[0, 0].astype(jnp.float32),
-        valid_ref[...],
+        valid_ref[0, :] != 0,
         scale=scale, softcap=softcap,
     ).astype(o_ref.dtype)
 
@@ -90,7 +90,7 @@ def decode_attention_pallas(
         kernel,
         grid=(B, Hkv),
         in_specs=[
-            pl.BlockSpec((W,), lambda b, h: (0,)),
+            pl.BlockSpec((1, W), lambda b, h: (0, 0)),  # valid as a row
             pl.BlockSpec((1, 1, G, D), lambda b, h: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, W, D), lambda b, h: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, W, D), lambda b, h: (b, h, 0, 0)),
@@ -98,7 +98,7 @@ def decode_attention_pallas(
         out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-    )(valid, q, k, v)
+    )(valid.astype(jnp.int32).reshape(1, W), q, k, v)
 
 
 def decode_attention_reference(q, k, v, valid, *, softcap: float = 0.0) -> jax.Array:

@@ -6,7 +6,9 @@ scratch that persists across KV steps; the output block is revisited and
 rescaled in place, then normalized on the last KV step.
 
 Block sizes default to (128, 128): MXU-aligned, and the working set
-(q, k, v, scores, acc tiles) stays well under VMEM.
+(q, k, v, scores, acc tiles) stays well under VMEM. Compiled for TPU, a
+block must be a multiple of 128 or the whole sequence (the kpos row block
+sits on lanes); `ops._flash_adapt` pads sequences that need it.
 
 GQA is expressed in the k/v BlockSpec index maps (h // group) — no repeated
 K/V materialization.
@@ -79,7 +81,7 @@ def _kernel(
     v = v_ref[0, 0].astype(jnp.float32)  # [BK, D]
     m_new, l_new, acc = _kv_block_step(
         (m_scr[...], l_scr[...], acc_scr[...]), q, k, v,
-        qpos_ref[...], kpos_ref[...],
+        qpos_ref[:, 0], kpos_ref[0, :],
         scale=scale, causal=causal, window=window, softcap=softcap,
     )
     m_scr[...] = m_new
@@ -120,8 +122,8 @@ def flash_attention_pallas(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_q,), lambda b, h, qi, ki: (qi,)),  # qpos
-            pl.BlockSpec((block_k,), lambda b, h, qi, ki: (ki,)),  # kpos
+            pl.BlockSpec((block_q, 1), lambda b, h, qi, ki: (qi, 0)),  # qpos
+            pl.BlockSpec((1, block_k), lambda b, h, qi, ki: (0, ki)),  # kpos
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki: (b, h // G, ki, 0)),
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki: (b, h // G, ki, 0)),
@@ -134,7 +136,17 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
-    )(qpos, kpos, q, k, v)
+    )(*position_blocks(qpos, kpos), q, k, v)
+
+
+def position_blocks(qpos, kpos):
+    """Positions in the layout the attention kernels block them in: qpos
+    as an [Sq, 1] column (block (block_q, 1)), kpos as a [1, Skv] row
+    (block (1, block_k)). A 1-D (block,) position block has a different
+    tiled layout in Mosaic than in XLA and is refused by the TPU compiler;
+    the kernels slice the blocks back to 1-D vectors, so the shared
+    `_kv_block_step` program is unchanged."""
+    return qpos.reshape(-1, 1), kpos.reshape(1, -1)
 
 
 def flash_attention_reference(

@@ -16,13 +16,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.minibatch_grad import F32
+
 
 def _kernel(x_ref, v_ref, p_ref, y_ref, o_ref, *, gamma: float, c_actual: int):
     x = x_ref[...]
     v = v_ref[...]
     u = jnp.dot(
         x.astype(jnp.float32), v.astype(jnp.float32).T,
-        preferred_element_type=jnp.float32,
+        preferred_element_type=jnp.float32, precision=F32,
     )  # [BN, C]
     p = p_ref[...].astype(jnp.float32)
     y = y_ref[...].astype(jnp.float32)

@@ -38,7 +38,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.flash_attention import NEG_INF, _kv_block_step
+from repro.kernels.flash_attention import (NEG_INF, _kv_block_step,
+                                          position_blocks)
 
 
 def _band_live(qp, kp, *, causal: bool, window: int):
@@ -67,7 +68,7 @@ def _skip_step_body(live, qpos_ref, q_ref, k_ref, v_ref, m_scr, l_scr,
         v = v_ref[0, 0].astype(jnp.float32)  # [BK, D]
         m_new, l_new, acc = _kv_block_step(
             (m_scr[...], l_scr[...], acc_scr[...]), q, k, v,
-            qpos_ref[...], kp,
+            qpos_ref[:, 0], kp,
             scale=scale, causal=causal, window=window, softcap=softcap,
         )
         m_scr[...] = m_new
@@ -105,8 +106,8 @@ def _local_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    kp = kpos_ref[...]
-    live = _band_live(qpos_ref[...], kp, causal=causal, window=window)
+    kp = kpos_ref[0, :]
+    live = _band_live(qpos_ref[:, 0], kp, causal=causal, window=window)
     _skip_step_body(live, qpos_ref, q_ref, k_ref, v_ref, m_scr, l_scr,
                     acc_scr, kp, scale=scale, causal=causal, window=window,
                     softcap=softcap)
@@ -118,7 +119,7 @@ def _local_kernel(
 
 
 def _sparse_kernel(
-    qpos_ref, kpos_ref, mask_ref, q_ref, k_ref, v_ref, o_ref,
+    mask_ref, qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
     m_scr, l_scr, acc_scr,
     *, scale: float, causal: bool, window: int, softcap: float, nk: int,
 ):
@@ -130,10 +131,10 @@ def _sparse_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    kp = kpos_ref[...]
+    kp = kpos_ref[0, :]
     live = jnp.logical_and(
-        mask_ref[0, 0] != 0,
-        _band_live(qpos_ref[...], kp, causal=causal, window=window))
+        mask_ref[pl.program_id(2), ki] != 0,
+        _band_live(qpos_ref[:, 0], kp, causal=causal, window=window))
     _skip_step_body(live, qpos_ref, q_ref, k_ref, v_ref, m_scr, l_scr,
                     acc_scr, kp, scale=scale, causal=causal, window=window,
                     softcap=softcap)
@@ -156,33 +157,38 @@ def _banded_call(kernel_fn, mask, q, k, v, qpos, kpos, *, causal, window,
         kernel_fn, scale=D**-0.5, causal=causal, window=window,
         softcap=float(softcap), nk=nk,
     )
-    in_specs = [
-        pl.BlockSpec((block_q,), lambda b, h, qi, ki: (qi,)),  # qpos
-        pl.BlockSpec((block_k,), lambda b, h, qi, ki: (ki,)),  # kpos
-    ]
-    args = [qpos, kpos]
+    # the sparse kernel's [nq, nk] block mask rides scalar prefetch (SMEM):
+    # index maps then take it as a trailing argument, hence the *_
+    args = []
     if mask is not None:
         assert mask.shape == (nq, nk), (mask.shape, nq, nk)
-        in_specs.append(pl.BlockSpec((1, 1), lambda b, h, qi, ki: (qi, ki)))
         args.append(mask.astype(jnp.int32))
-    in_specs += [
-        pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
-        pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki: (b, h // G, ki, 0)),
-        pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki: (b, h // G, ki, 0)),
-    ]
-    args += [q, k, v]
-    return pl.pallas_call(
-        kernel,
+    args += [*position_blocks(qpos, kpos), q, k, v]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=0 if mask is None else 1,
         grid=(B, Hq, nq, nk),
-        in_specs=in_specs,
+        in_specs=[
+            pl.BlockSpec((block_q, 1), lambda b, h, qi, ki, *_: (qi, 0)),
+            pl.BlockSpec((1, block_k), lambda b, h, qi, ki, *_: (0, ki)),
+            pl.BlockSpec((1, 1, block_q, D),
+                         lambda b, h, qi, ki, *_: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, block_k, D),
+                         lambda b, h, qi, ki, *_: (b, h // G, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, D),
+                         lambda b, h, qi, ki, *_: (b, h // G, ki, 0)),
+        ],
         out_specs=pl.BlockSpec((1, 1, block_q, D),
-                               lambda b, h, qi, ki: (b, h, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+                               lambda b, h, qi, ki, *_: (b, h, qi, 0)),
         scratch_shapes=[
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
     )(*args)
 

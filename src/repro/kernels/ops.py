@@ -34,6 +34,7 @@ from repro.kernels.local_attention import (
 from repro.kernels.infl_scores import infl_scores_pallas
 from repro.kernels.paged_attention import (
     combine_pages,
+    page_tile_rows,
     paged_attention_partials_pallas,
     paged_attention_partials_quant_pallas,
     paged_attention_partials_quant_reference,
@@ -143,35 +144,37 @@ def lr_hvp(w, v, Xa, weights, l2: float, P=None):
     return h[:C, : Xa.shape[1]] + l2 * v.astype(jnp.float32)
 
 
-def _pad_gather_rows(arrs, mult: int):
-    """Row-pad arrays that will be *gathered from*: always leaves at least one
-    zeroed tail row, so padded gather indices (pointing at the original row
-    count) land on zeros and contribute exactly 0."""
-    return [_pad_rows(a, mult)[0] if a.shape[0] % mult else
-            jnp.pad(a, [(0, mult)] + [(0, 0)] * (a.ndim - 1)) for a in arrs]
+# rows gathered per grid step of the compiled mini-batch kernel: the
+# [block, 1, d+1] f32 gather scratch is ~2.2 MB at d+1 = 2,176 lanes
+GATHER_BLOCK = 256
 
 
 @functools.partial(jax.jit, static_argnames=("l2",))
 def minibatch_grad(w, Xa, Y, weights, idx, l2: float):
     """Fused gather + mini-batch gradient (constructor-phase hot op).
 
-    Interpret mode runs the kernel UNPADDED: the body is then the same
-    floating-point program as the reference scan step, which is what makes
+    The kernel DMAs the Xa batch rows itself; the batch's labels and
+    weights are gathered here (tiny). Interpret mode runs the kernel
+    UNPADDED in one chunk: the body is then the same floating-point program
+    as the reference scan step, which is what makes
     sgd_train/deltagrad_replay bit-identical across backends. On TPU, lanes
-    pad to 128 and the gathered batch pads to sublane multiples with indices
-    pointing at a zeroed row (weight 0 => exact-zero contribution)."""
+    pad to 128 and the batch pads to `GATHER_BLOCK`-row chunks whose padded
+    slots point at row 0 with weight 0 (exact-zero contribution)."""
     idx = idx.astype(jnp.int32)
+    yb, wb = Y[idx], weights[idx]
     if _interpret():
-        return minibatch_grad_pallas(w, Xa, Y, weights, idx, l2, interpret=True)
+        return minibatch_grad_pallas(w, Xa, yb, wb, idx, l2, interpret=True)
     C = w.shape[0]
     bs = idx.shape[0]
     lane = 128
+    bb = min(GATHER_BLOCK, -(-bs // 8) * 8)
+    pad = (-bs) % bb
     wp = _pad_dim(_pad_dim(w, 0, lane), 1, lane)
-    Xp, Yp, w8p = _pad_gather_rows(
-        [_pad_dim(Xa, 1, lane), _pad_dim(Y, 1, lane), weights], 8)
-    idxp = jnp.pad(idx, (0, (-bs) % 8), constant_values=Xa.shape[0])
-    g = minibatch_grad_pallas(wp, Xp, Yp, w8p, idxp, l2, n_batch=bs,
-                              c_actual=C, interpret=False)
+    g = minibatch_grad_pallas(
+        wp, _pad_dim(Xa, 1, lane),
+        jnp.pad(_pad_dim(yb, 1, lane), ((0, pad), (0, 0))),
+        jnp.pad(wb, (0, pad)), jnp.pad(idx, (0, pad)), l2,
+        n_batch=bs, c_actual=C, block_b=bb, interpret=False)
     return g[:C, : Xa.shape[1]]
 
 
@@ -179,24 +182,25 @@ def minibatch_grad(w, Xa, Y, weights, idx, l2: float):
 def replay_correction(w, Xa, Y_old, Y_new, w_old, w_new, ci, cm,
                       batch_size: int):
     """Fused gather + DeltaGrad-L replay correction. Same interpret-unpadded
-    bit-parity contract as `minibatch_grad`; TPU row padding extends ci with
-    pointers to a zeroed row and cm with zeros (exact-zero contribution)."""
+    bit-parity contract as `minibatch_grad`; TPU padding extends ci with
+    pointers to row 0 and cm with zeros (exact-zero contribution)."""
     ci = ci.astype(jnp.int32)
+    yo, yn, wo, wn = Y_old[ci], Y_new[ci], w_old[ci], w_new[ci]
     if _interpret():
-        return replay_correction_pallas(w, Xa, Y_old, Y_new, w_old, w_new,
-                                        ci, cm, batch_size, interpret=True)
+        return replay_correction_pallas(w, Xa, yo, yn, wo, wn, ci, cm,
+                                        batch_size, interpret=True)
     C = w.shape[0]
-    r = ci.shape[0]
     lane = 128
-    wp = _pad_dim(_pad_dim(w, 0, lane), 1, lane)
-    Xp, Yop, Ynp, wop, wnp = _pad_gather_rows(
-        [_pad_dim(Xa, 1, lane), _pad_dim(Y_old, 1, lane),
-         _pad_dim(Y_new, 1, lane), w_old, w_new], 8)
-    pad = (-r) % 8
-    cip = jnp.pad(ci, (0, pad), constant_values=Xa.shape[0])
-    cmp_ = jnp.pad(cm, (0, pad))
-    g = replay_correction_pallas(wp, Xp, Yop, Ynp, wop, wnp, cip, cmp_,
-                                 batch_size, c_actual=C, interpret=False)
+    pad = (-ci.shape[0]) % 8
+
+    def rows(a):
+        return jnp.pad(a, [(0, pad)] + [(0, 0)] * (a.ndim - 1))
+
+    g = replay_correction_pallas(
+        _pad_dim(_pad_dim(w, 0, lane), 1, lane), _pad_dim(Xa, 1, lane),
+        rows(_pad_dim(yo, 1, lane)), rows(_pad_dim(yn, 1, lane)),
+        rows(wo), rows(wn), rows(ci), rows(cm), batch_size, c_actual=C,
+        interpret=False)
     return g[:C, : Xa.shape[1]]
 
 
@@ -218,21 +222,62 @@ def _attn_blocks(Sq: int, Skv: int) -> tuple:
     return pick(Sq), pick(Skv)
 
 
+# padded key positions: past every real query, so the causal mask drops them
+_PAD_POS = 2**30
+
+
+def _compiled_seq_pad(q, k, v, qpos, kpos, spec, extra):
+    """Pad sequences for the compiled (non-interpret) attention kernels.
+
+    Their position blocks sit on lanes, so a block is either the whole
+    sequence or a multiple of 128: a sequence over 128 that is not a
+    multiple of 128 pads up to one. Padded keys sit at `_PAD_POS`, which
+    the causal mask drops; padded query rows are sliced off by the caller.
+    The interpret path (and every power-of-two bucket the engine prefills
+    at) never pads."""
+    def need(S):
+        return (-S) % 128 if S > 128 else 0
+
+    pq, pk = need(q.shape[2]), need(k.shape[2])
+    if not (pq or pk):
+        return q, k, v, qpos, kpos
+    if not spec.causal or "block_mask" in extra:
+        raise NotImplementedError(
+            "compiled attention pads sequences over 128 to a multiple of 128,"
+            " which needs a causal mask and no block mask; got "
+            f"Sq={q.shape[2]}, Skv={k.shape[2]}, causal={spec.causal}")
+    seq = lambda x, n: jnp.pad(x, ((0, 0), (0, 0), (0, n), (0, 0)))
+    return (seq(q, pq), seq(k, pk), seq(v, pk),
+            jnp.pad(qpos, (0, pq), constant_values=_PAD_POS),
+            jnp.pad(kpos, (0, pk), constant_values=_PAD_POS))
+
+
+def _to_kernel_layout(q, k, v, qpos, kpos, spec, extra):
+    """q [B,S,H,D] -> kernel layout [B,H,S,D] (+ compiled-path padding), one
+    block-size choice, one position cast."""
+    qt = q.transpose(0, 2, 1, 3)
+    kt = k.transpose(0, 2, 1, 3)
+    vt = v.transpose(0, 2, 1, 3)
+    qpos, kpos = qpos.astype(jnp.int32), kpos.astype(jnp.int32)
+    if extra.get("interpret") is False:
+        qt, kt, vt, qpos, kpos = _compiled_seq_pad(qt, kt, vt, qpos, kpos,
+                                                   spec, extra)
+    return qt, kt, vt, qpos, kpos, _attn_blocks(qt.shape[2], kt.shape[2])
+
+
 def _flash_adapt(inner, q, k, v, qpos, kpos, spec, **extra):
     """Shared model-layout adapter for both flash forms: q [B,S,H,D] ->
     kernel layout [B,H,S,D], one block-size choice, one position cast. ONE
     function on purpose — if the two forms adapted separately, an edit to
     one side would silently break the bit-parity contract."""
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    bq, bk = _attn_blocks(qt.shape[2], kt.shape[2])
+    qt, kt, vt, qp, kp, (bq, bk) = _to_kernel_layout(q, k, v, qpos, kpos,
+                                                     spec, extra)
     o = inner(
-        qt, kt, vt, qpos.astype(jnp.int32), kpos.astype(jnp.int32),
+        qt, kt, vt, qp, kp,
         causal=spec.causal, window=spec.window, softcap=spec.logit_softcap,
         block_q=bq, block_k=bk, **extra,
     )
-    return o.transpose(0, 2, 1, 3)
+    return o[:, :, : q.shape[1]].transpose(0, 2, 1, 3)
 
 
 def flash_attention(q, k, v, qpos, kpos, spec):
@@ -293,15 +338,15 @@ def _chunked_adapt(inner, q, k, v, qpos, kpos, spec, chunk, **extra):
     transpose + `_attn_blocks` choice as `_flash_adapt`, but the output is
     the (m, l, acc) split-K partial triple, left in kernel layout for
     `chunked_prefill_finish` / the head-sharded partials shard_map."""
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    bq, bk = _attn_blocks(qt.shape[2], kt.shape[2])
-    return inner(
-        qt, kt, vt, qpos.astype(jnp.int32), kpos.astype(jnp.int32),
+    qt, kt, vt, qp, kp, (bq, bk) = _to_kernel_layout(q, k, v, qpos, kpos,
+                                                     spec, extra)
+    m, l, acc = inner(
+        qt, kt, vt, qp, kp,
         causal=spec.causal, window=spec.window, softcap=spec.logit_softcap,
         chunk=chunk, block_q=bq, block_k=bk, **extra,
     )
+    Sq = q.shape[1]
+    return m[..., :Sq], l[..., :Sq], acc[..., :Sq, :]
 
 
 def chunked_prefill_partials(q, k, v, qpos, kpos, spec, chunk: int):
@@ -396,6 +441,15 @@ def decode_attention_ref(q, k, v, valid, spec):
     return o.reshape(B, 1, Hq, D)
 
 
+def _check_page_rows(k_pages):
+    """Backstop for direct op callers (`ServeEngine` validates at config
+    time): compiled pages must be whole sublane tiles of the pool dtype."""
+    rows = page_tile_rows(k_pages.dtype)
+    assert k_pages.shape[1] % rows == 0, (
+        f"TPU paged cache needs page_size % {rows} == 0 for "
+        f"{k_pages.dtype} pools, got {k_pages.shape[1]}")
+
+
 def _paged_layout(q, k_pages):
     """Model layout -> paged-kernel layout: q [B,1,Hq,D] -> [B,Hkv,G,D].
     The page pools already carry the kernel layout ([N_pages, P, Hkv, D] —
@@ -425,7 +479,7 @@ def paged_decode_partials(q, k_pages, v_pages, pages, pos, spec):
         return paged_attention_partials_pallas(
             qg, k_pages, v_pages, pages, pos, window=spec.window,
             softcap=spec.logit_softcap, interpret=True)
-    assert k_pages.shape[1] % 8 == 0, "TPU paged cache needs page_size % 8 == 0"
+    _check_page_rows(k_pages)
     scale = D**-0.5
     qp = _pad_dim(_pad_dim(qg, 2, 8), 3, 128)
     kp = _pad_dim(k_pages, 3, 128)
@@ -460,9 +514,10 @@ def paged_decode_attention(q, k_pages, v_pages, pages, pos, spec):
     kernel unpadded — the same floating-point program as
     `paged_decode_attention_ref` — preserving the serving bit-parity
     contract; on TPU, G pads to sublanes and D to 128 lanes with the scale
-    pinned to the true head dim (page_size must be a sublane multiple —
-    `ServeEngine` validates that at config time; `paged_decode_partials`
-    carries the backstop assert for direct op callers)."""
+    pinned to the true head dim (page_size must be a multiple of the pool
+    dtype's sublane tile, `page_tile_rows` — `ServeEngine` validates that
+    at config time; `paged_decode_partials` carries the backstop assert for
+    direct op callers)."""
     m, l, acc = paged_decode_partials(q, k_pages, v_pages, pages, pos, spec)
     return paged_decode_finish(m, l, acc, q)
 
@@ -497,7 +552,7 @@ def quant_paged_decode_partials(q, k_pages, v_pages, k_scale, v_scale,
         return paged_attention_partials_quant_pallas(
             qg, k_pages, v_pages, k_scale, v_scale, pages, pos,
             window=spec.window, softcap=spec.logit_softcap, interpret=True)
-    assert k_pages.shape[1] % 8 == 0, "TPU paged cache needs page_size % 8 == 0"
+    _check_page_rows(k_pages)
     scale = D**-0.5
     qp = _pad_dim(_pad_dim(qg, 2, 8), 3, 128)
     kp = _pad_dim(k_pages, 3, 128)

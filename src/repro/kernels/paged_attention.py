@@ -3,12 +3,16 @@
 The production form of the serving decode op: each batch slot's KV history
 lives in fixed-size pages of a shared physical pool ([N_pages, P, Hkv, D]),
 indexed through a per-slot block table ([B, n_pages] physical page ids) —
-the vLLM layout at miniature scale. Per (batch, kv-head) cell the kernel
-STREAMS the slot's pages one page per grid step (W-chunking: only a single
-[P, D] page block is ever resident in VMEM, so caches far past VMEM work
-unchanged). The page id for each grid step comes from the block table via
-scalar-prefetch BlockSpec index maps, so the gather is a DMA schedule, not
-a materialized [B, W, Hkv, D] copy.
+the vLLM layout at miniature scale. Per batch slot the kernel STREAMS the
+slot's pages one page per grid step (W-chunking: only a single
+[P, Hkv, D] page block — every kv head of one page — is ever resident in
+VMEM, so caches far past VMEM work unchanged) and runs the per-head program
+on each head's [P, D] slice. The page id for each grid step comes from the
+block table via scalar-prefetch BlockSpec index maps, so the gather is a
+DMA schedule, not a materialized [B, W, Hkv, D] copy. Streaming whole
+pages keeps the block's tiled trailing dims the pool's own (Hkv, D), which
+the TPU compiler accepts at any page size; a one-head (P, 1, D) block is
+refused by its (8, 128) tiling rule.
 
 Split-softmax structure (flash-decoding's split-K shape): the kernel writes
 an INDEPENDENT self-normalized partial softmax per page — (m_j, l_j, acc_j)
@@ -122,11 +126,26 @@ def combine_pages(m, l, acc):
     return acc_tot / jnp.maximum(l_tot, 1e-30)[..., None]
 
 
+def page_tile_rows(dtype) -> int:
+    """Rows of one native TPU sublane tile for a page-pool dtype: 8 for
+    32-bit, 16 for bf16, 32 for int8. A compiled page's per-head [P, D]
+    operand fills whole tiles only when P is a multiple of this — the rule
+    `ServeEngine` validates page sizes against."""
+    return 8 * (4 // jnp.dtype(dtype).itemsize)
+
+
+def _emit_neutral(m_ref, l_ref, acc_ref):
+    """Trash page: no data by construction — write the neutral partial for
+    every head without touching k/v (combine_pages weighs it to exactly 0)."""
+    m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+
 def _kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, *,
             scale: float, window: int, softcap: float, page_size: int):
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    G, D = q_ref.shape[2], q_ref.shape[3]
+    j = pl.program_id(1)
 
     @pl.when(pt_ref[b, j] != 0)
     def _compute():
@@ -134,24 +153,65 @@ def _kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, *,
         # — 1D iota does not lower on TPU)
         kpos = j * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, page_size), 1)[0]
-        m, l, acc = _page_partial(
-            q_ref[0, 0].astype(jnp.float32),
-            k_ref[0, :, 0, :].astype(jnp.float32),
-            v_ref[0, :, 0, :].astype(jnp.float32),
-            kpos, pos_ref[b],
-            scale=scale, window=window, softcap=softcap,
-        )
-        m_ref[0, 0, 0] = m
-        l_ref[0, 0, 0] = l
-        acc_ref[0, 0, 0] = acc
+        for h in range(k_ref.shape[2]):
+            m, l, acc = _page_partial(
+                q_ref[0, h].astype(jnp.float32),
+                k_ref[0, :, h, :].astype(jnp.float32),
+                v_ref[0, :, h, :].astype(jnp.float32),
+                kpos, pos_ref[b],
+                scale=scale, window=window, softcap=softcap,
+            )
+            m_ref[0, 0, h] = m
+            l_ref[0, 0, h] = l
+            acc_ref[0, 0, h] = acc
 
     @pl.when(pt_ref[b, j] == 0)
     def _neutral():
-        # trash page: no data by construction — emit the neutral partial
-        # without touching k/v (combine_pages weighs it to exactly 0)
-        m_ref[0, 0, 0] = jnp.full((G,), NEG_INF, jnp.float32)
-        l_ref[0, 0, 0] = jnp.zeros((G,), jnp.float32)
-        acc_ref[0, 0, 0] = jnp.zeros((G, D), jnp.float32)
+        _emit_neutral(m_ref, l_ref, acc_ref)
+
+
+def _page_partials_call(kernel, q, k_pages, v_pages, page_table, pos, *,
+                        interpret: bool, extra=(), extra_specs=()):
+    """Shared pallas_call plumbing of the two paged kernels.
+
+    Grid (B, n_pages) with pages innermost: each step DMAs ONE physical
+    page — all kv heads of it, a [P, Hkv, D] block whose tiled trailing
+    dims are the pool's own (Hkv, D), so any page size is a legal block —
+    through the scalar-prefetched block table, and writes that page's
+    independent partial for every head. The partials come out [B, n_pages,
+    Hkv, ...] (trailing (Hkv, G) blocks are whole dims, legal for the
+    compiler) and are transposed to the [B, Hkv, n_pages, ...] layout
+    `combine_pages` and the head-sharded specs read."""
+    B, Hkv, G, D = q.shape
+    P = k_pages.shape[1]
+    n_pages = page_table.shape[1]
+    page = pl.BlockSpec((1, P, Hkv, D), lambda b, j, pt, ps: (pt[b, j], 0, 0, 0))
+    part = pl.BlockSpec((1, 1, Hkv, G), lambda b, j, pt, ps: (b, j, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,  # page_table, pos feed the index maps
+        grid=(B, n_pages),
+        in_specs=[
+            pl.BlockSpec((1, Hkv, G, D), lambda b, j, pt, ps: (b, 0, 0, 0)),
+            page, page, *extra_specs,
+        ],
+        out_specs=[
+            part, part,
+            pl.BlockSpec((1, 1, Hkv, G, D),
+                         lambda b, j, pt, ps: (b, j, 0, 0, 0)),
+        ],
+    )
+    m, l, acc = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((B, n_pages, Hkv, G), jnp.float32),
+            jax.ShapeDtypeStruct((B, n_pages, Hkv, G), jnp.float32),
+            jax.ShapeDtypeStruct((B, n_pages, Hkv, G, D), jnp.float32),
+        ],
+        interpret=interpret,
+    )(page_table, pos, q, k_pages, v_pages, *extra)
+    return (m.transpose(0, 2, 1, 3), l.transpose(0, 2, 1, 3),
+            acc.transpose(0, 2, 1, 3, 4))
 
 
 def paged_attention_partials_pallas(
@@ -170,78 +230,49 @@ def paged_attention_partials_pallas(
     (m [B, Hkv, n_pages, G], l [B, Hkv, n_pages, G],
     acc [B, Hkv, n_pages, G, D]) in f32 — feed `combine_pages`.
 
-    Grid (B, Hkv, n_pages) with pages innermost: each step DMAs exactly one
-    [P, D] page per k/v (index-mapped through the scalar-prefetched block
-    table) and writes that page's independent partial — cache size never
-    constrains VMEM. `scale` overrides the D**-0.5 default when the caller
-    lane-padded D."""
-    B, Hkv, G, D = q.shape
-    P = k_pages.shape[1]
-    n_pages = page_table.shape[1]
+    Each grid step DMAs exactly one [P, Hkv, D] page per k/v (index-mapped
+    through the scalar-prefetched block table) and writes that page's
+    independent per-head partials — cache size never constrains VMEM.
+    `scale` overrides the D**-0.5 default when the caller lane-padded D."""
+    D = q.shape[3]
     kernel = functools.partial(
         _kernel, scale=float(scale or D**-0.5), window=int(window),
-        softcap=float(softcap), page_size=P,
+        softcap=float(softcap), page_size=k_pages.shape[1],
     )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # page_table, pos feed the index maps
-        grid=(B, Hkv, n_pages),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, j, pt, ps: (b, h, 0, 0)),
-            pl.BlockSpec((1, P, 1, D),
-                         lambda b, h, j, pt, ps: (pt[b, j], 0, h, 0)),
-            pl.BlockSpec((1, P, 1, D),
-                         lambda b, h, j, pt, ps: (pt[b, j], 0, h, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, G), lambda b, h, j, pt, ps: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, 1, G), lambda b, h, j, pt, ps: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, 1, G, D),
-                         lambda b, h, j, pt, ps: (b, h, j, 0, 0)),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, n_pages, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, n_pages, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, n_pages, G, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )(page_table, pos, q, k_pages, v_pages)
+    return _page_partials_call(kernel, q, k_pages, v_pages, page_table, pos,
+                               interpret=interpret)
 
 
 def _kernel_quant(pt_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
                   m_ref, l_ref, acc_ref, *, scale: float, window: int,
                   softcap: float, page_size: int):
-    """`_kernel` over int8 pages: identical structure, with the streamed
-    [P, D] code block dequantized in-VMEM by the shared `_dequant_page`
-    cell against the (1, 1) scale block the grid step prefetched alongside
-    it. Everything downstream of the dequant is `_page_partial` verbatim."""
+    """`_kernel` over int8 pages: identical structure, with each head's
+    streamed [P, D] code block dequantized in-VMEM by the shared
+    `_dequant_page` cell against that head's (1, 1) slice of the page's
+    [1, Hkv] scale row, prefetched alongside it. Everything downstream of
+    the dequant is `_page_partial` verbatim."""
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    G, D = q_ref.shape[2], q_ref.shape[3]
+    j = pl.program_id(1)
 
     @pl.when(pt_ref[b, j] != 0)
     def _compute():
         kpos = j * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, page_size), 1)[0]
-        m, l, acc = _page_partial(
-            q_ref[0, 0].astype(jnp.float32),
-            _dequant_page(k_ref[0, :, 0, :], ks_ref[0, 0]),
-            _dequant_page(v_ref[0, :, 0, :], vs_ref[0, 0]),
-            kpos, pos_ref[b],
-            scale=scale, window=window, softcap=softcap,
-        )
-        m_ref[0, 0, 0] = m
-        l_ref[0, 0, 0] = l
-        acc_ref[0, 0, 0] = acc
+        for h in range(k_ref.shape[2]):
+            m, l, acc = _page_partial(
+                q_ref[0, h].astype(jnp.float32),
+                _dequant_page(k_ref[0, :, h, :], ks_ref[0, :, h:h + 1]),
+                _dequant_page(v_ref[0, :, h, :], vs_ref[0, :, h:h + 1]),
+                kpos, pos_ref[b],
+                scale=scale, window=window, softcap=softcap,
+            )
+            m_ref[0, 0, h] = m
+            l_ref[0, 0, h] = l
+            acc_ref[0, 0, h] = acc
 
     @pl.when(pt_ref[b, j] == 0)
     def _neutral():
-        m_ref[0, 0, 0] = jnp.full((G,), NEG_INF, jnp.float32)
-        l_ref[0, 0, 0] = jnp.zeros((G,), jnp.float32)
-        acc_ref[0, 0, 0] = jnp.zeros((G, D), jnp.float32)
+        _emit_neutral(m_ref, l_ref, acc_ref)
 
 
 def paged_attention_partials_quant_pallas(
@@ -259,49 +290,23 @@ def paged_attention_partials_quant_pallas(
     interpret: bool = False,
 ):
     """`paged_attention_partials_pallas` over the int8 page pool: the same
-    (B, Hkv, n_pages) grid streams each [P, D] int8 page PLUS its (1, 1)
-    per-(page, head) scale block through the same table-prefetched index
-    maps (pt[b, j] for the page axis, h for the head axis) and dequantizes
-    in-VMEM — the pool crosses HBM at half the bf16 byte count and is never
-    materialized densely in any precision. (TPU-ideal int8 tiling is
-    (32, 128); the serving page sizes trade that for page granularity,
-    which interpret-mode CI never notices.)"""
-    B, Hkv, G, D = q.shape
-    P = k_pages.shape[1]
-    n_pages = page_table.shape[1]
+    (B, n_pages) grid streams each [P, Hkv, D] int8 page PLUS its page's
+    [1, Hkv] scale row (the [N_pages, Hkv] scales viewed as
+    [N_pages, 1, Hkv], so the row is a whole trailing-dims block) through
+    the same table-prefetched index map and dequantizes in-VMEM — the pool
+    crosses HBM at half the bf16 byte count and is never materialized
+    densely in any precision."""
+    D = q.shape[3]
+    NP, Hkv = k_scale.shape
     kernel = functools.partial(
         _kernel_quant, scale=float(scale or D**-0.5), window=int(window),
-        softcap=float(softcap), page_size=P,
+        softcap=float(softcap), page_size=k_pages.shape[1],
     )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # page_table, pos feed the index maps
-        grid=(B, Hkv, n_pages),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, j, pt, ps: (b, h, 0, 0)),
-            pl.BlockSpec((1, P, 1, D),
-                         lambda b, h, j, pt, ps: (pt[b, j], 0, h, 0)),
-            pl.BlockSpec((1, P, 1, D),
-                         lambda b, h, j, pt, ps: (pt[b, j], 0, h, 0)),
-            pl.BlockSpec((1, 1), lambda b, h, j, pt, ps: (pt[b, j], h)),
-            pl.BlockSpec((1, 1), lambda b, h, j, pt, ps: (pt[b, j], h)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, G), lambda b, h, j, pt, ps: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, 1, G), lambda b, h, j, pt, ps: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, 1, G, D),
-                         lambda b, h, j, pt, ps: (b, h, j, 0, 0)),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, n_pages, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, n_pages, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, n_pages, G, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )(page_table, pos, q, k_pages, v_pages, k_scale, v_scale)
+    srow = pl.BlockSpec((1, 1, Hkv), lambda b, j, pt, ps: (pt[b, j], 0, 0))
+    return _page_partials_call(
+        kernel, q, k_pages, v_pages, page_table, pos, interpret=interpret,
+        extra=(k_scale.reshape(NP, 1, Hkv), v_scale.reshape(NP, 1, Hkv)),
+        extra_specs=(srow, srow))
 
 
 def paged_attention_partials_quant_reference(

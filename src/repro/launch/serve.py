@@ -11,6 +11,10 @@ purely a performance/scale choice. `pallas_sharded` additionally shards the
 KV cache (ring leaves and paged page pools alike) head-wise over the mesh
 model axis.
 
+`--reduce smoke` (default) serves the arch's smoke-size reduced config in
+f32; `--reduce none` serves its full published config in bf16 (the paged
+pools then need pages of 16 tokens on TPU; 32 with `--kv_dtype int8`).
+
 `--cache` selects the cache discipline: `paged` (block-table paged cache
 with per-slot decode positions — batching-invariant outputs), `ring` (the
 seed engine's shared-counter ring, kept as the differential oracle), or
@@ -59,6 +63,9 @@ def main(argv=None) -> dict:
     """CLI entry; returns a summary dict (also used by tests/examples)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-1b")
+    ap.add_argument("--reduce", default="smoke", choices=["smoke", "none"],
+                    help="smoke = reduced config in f32; none = the full "
+                         "published config in bf16")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt_len", type=int, default=32)
@@ -67,7 +74,7 @@ def main(argv=None) -> dict:
                     help="reference | pallas | pallas_sharded")
     ap.add_argument("--cache", default="auto",
                     help="auto | paged | ring (see repro.serving.ServeConfig)")
-    ap.add_argument("--page_size", type=int, default=8,
+    ap.add_argument("--page_size", type=int, default=16,
                     help="tokens per physical page (paged cache)")
     ap.add_argument("--share_prefix", action=argparse.BooleanOptionalAction,
                     default=True,
@@ -98,7 +105,9 @@ def main(argv=None) -> dict:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
-    cfg = reduced(get_config(args.arch))
+    cfg = get_config(args.arch)
+    if args.reduce == "smoke":
+        cfg = reduced(cfg)
     if args.attn:
         if args.attn == "full":
             cfg = dc_replace(cfg, attn_kind="full", sliding_window=0)
@@ -108,8 +117,9 @@ def main(argv=None) -> dict:
         else:
             raise SystemExit(
                 f"unknown --attn {args.attn!r} (want 'window:<W>' or 'full')")
-    model = Model(cfg)
     import jax.numpy as jnp
+    model = Model(cfg, param_dtype=(jnp.float32 if args.reduce == "smoke"
+                                    else jnp.bfloat16))
     model.kv_dtype = {"int8": jnp.int8, "bf16": jnp.bfloat16,
                       "": None}[args.kv_dtype]
     params = model.init(jax.random.key(args.seed))
@@ -154,4 +164,7 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -142,6 +142,22 @@ def greedy(logits: jax.Array) -> jax.Array:
     return jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)[:, None]
 
 
+def check_page_size(page_size: int, pool_dtype, *, compiled: bool) -> None:
+    """Validate a paged-cache page size for its pool dtype. Compiled (TPU)
+    pages must be whole native sublane tiles of the pool dtype — 8 rows for
+    f32, 16 for bf16, 32 for int8 (`kernels.paged_attention.page_tile_rows`)
+    — so each head's [P, D] page slice is a full tile operand."""
+    from repro.kernels.paged_attention import page_tile_rows
+
+    if page_size < 1:
+        raise ValueError(f"page_size must be >= 1, got {page_size}")
+    rows = page_tile_rows(pool_dtype)
+    if compiled and page_size % rows:
+        raise ValueError(
+            f"TPU paged cache with {jnp.dtype(pool_dtype).name} pools needs "
+            f"page_size % {rows} == 0, got {page_size}")
+
+
 def bucket_len(n: int, lo: int = 8) -> int:
     """Round `n` up to a power-of-two bucket (>= lo): the paged engine
     prefills at bucketed widths so many staggered request lengths trace
@@ -164,7 +180,7 @@ class ServeConfig:
     batch_size: int = 4
     max_len: int = 256          # per-request prompt + decode budget bound
     cache: str = "auto"         # "auto" | "paged" | "ring"
-    page_size: int = 8          # tokens per physical page (paged only)
+    page_size: int = 16         # tokens per physical page (paged only)
     num_pages: int = 0          # physical pool size; 0 = auto-size
     bucket_min: int = 8         # smallest power-of-two prefill bucket
     trace_logits: bool = False  # record per-request logits on Request.logits
@@ -283,15 +299,11 @@ class ServeEngine:
             self._prefill = jax.jit(
                 make_prefill_step(model, self.backend, cache_len=cfg.max_len))
         else:
-            if cfg.page_size < 1:
-                raise ValueError(f"page_size must be >= 1, got {cfg.page_size}")
-            if jax.default_backend() == "tpu" and cfg.page_size % 8:
-                # compiled pages are (page_size, D) sublane tiles; interpret
-                # mode (CPU) takes any size — fail at config time, not on
-                # the first decode step after admission+prefill work
-                raise ValueError(
-                    f"TPU paged cache needs page_size % 8 == 0, "
-                    f"got {cfg.page_size}")
+            pool_dtype = jnp.int8 if self._quant else model.param_dtype
+            # interpret mode (CPU) takes any page size; fail at config time,
+            # not on the first decode step after admission+prefill work
+            check_page_size(cfg.page_size, pool_dtype,
+                            compiled=jax.default_backend() == "tpu")
             self.table_pages = -(-cfg.max_len // cfg.page_size)
             # auto pool: full per-slot coverage + the reserved trash page
             self.num_pages = cfg.num_pages or (
@@ -728,7 +740,7 @@ class ServeEngine:
                       "prefix_hit_tokens": 0, "prefix_hits": 0,
                       "spec_proposed": 0, "spec_accepted": 0,
                       "cow_copies": 0, "pages_retired": 0,
-                      "decode_rounds": 0, "slot_rounds": 0,
+                      "decode_rounds": 0, "slot_rounds": 0, "joins": 0,
                       "prefix_evictions": self._prefix_evictions}
         nxt = jnp.zeros((self.B, 1), jnp.int32)
         cache, nxt = self._admit_idle_slots(pending, done, cache, nxt,
@@ -970,6 +982,10 @@ class ServeEngine:
             width = self._bucket(L)
             j.entry_width = width
             self.stats["prompt_tokens"] += L
+            # admitted after decoding began while another slot is still
+            # decoding: a mid-stream join
+            self.stats["joins"] += int(self.stats["decode_rounds"] > 0 and any(
+                a is not None for a in active))
             if n_share:
                 Ls = n_share * P
                 tail_w = self._bucket(L - Ls)

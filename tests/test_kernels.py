@@ -84,6 +84,29 @@ def test_flash_attention(B, Hq, Hkv, Sq, Skv, D, causal, window, dtype, rng):
     )
 
 
+def test_compiled_seq_pad_masks_padded_keys(rng):
+    """The compiled attention path pads a sequence over 128 to a multiple
+    of 128 (its kpos row block sits on lanes): padded keys sit past every
+    query and the causal mask drops them, so the real rows match the
+    unpadded attention; a non-causal spec cannot be padded and raises."""
+    from repro.models.attention import AttnSpec
+
+    B, H, S, D = 1, 2, 200, 32
+    ks = jax.random.split(rng, 3)
+    q, k, v = (jax.random.normal(kk, (B, H, S, D), jnp.float32) for kk in ks)
+    pos = jnp.arange(S, dtype=jnp.int32)
+    qp, kp, vp, qpp, kpp = ops._compiled_seq_pad(q, k, v, pos, pos,
+                                                 AttnSpec(), {})
+    assert qp.shape[2] == kp.shape[2] == 256
+    out = flash_attention_pallas(qp, kp, vp, qpp, kpp, block_q=128,
+                                 block_k=128, interpret=True)[:, :, :S]
+    want = ref.flash_attention_ref(q, k, v, pos, pos, causal=True, window=0)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+    with pytest.raises(NotImplementedError, match="causal"):
+        ops._compiled_seq_pad(q, k, v, pos, pos, AttnSpec(causal=False), {})
+
+
 def test_ops_wrappers_unaligned(rng):
     """Public wrappers handle non-128-aligned shapes via padding."""
     from repro.core import lr_head

@@ -28,7 +28,8 @@ from repro.models import Model
 from repro.models.attention import (AttnSpec, KVCache, PagedKVCache,
                                     QuantKVCache, QuantPagedKVCache,
                                     ring_valid)
-from repro.serving.engine import Request, ServeConfig, ServeEngine
+from repro.serving.engine import (Request, ServeConfig, ServeEngine,
+                                  check_page_size)
 
 _SEL = [b.strip() for b in os.environ.get(
     "REPRO_TEST_BACKENDS", ",".join(BACKENDS)).split(",") if b.strip()]
@@ -618,6 +619,41 @@ def test_window_retirement_bitwise_neutral_and_lifts_concurrency(rng):
     conc_on = e2_on.stats["slot_rounds"] / e2_on.stats["decode_rounds"]
     conc_off = e2_off.stats["slot_rounds"] / e2_off.stats["decode_rounds"]
     assert conc_on > conc_off, (conc_on, conc_off)
+
+
+@pytest.mark.parametrize("dtype,rows", [(jnp.float32, 8),
+                                        (jnp.bfloat16, 16), (jnp.int8, 32)])
+def test_page_size_check_follows_pool_dtype(dtype, rows):
+    """Compiled (TPU) pages must be whole sublane tiles of the pool dtype:
+    8 rows for f32, 16 for bf16, 32 for int8. The check runs at engine
+    config time; interpret mode takes any positive page size."""
+    check_page_size(rows, dtype, compiled=True)
+    check_page_size(2 * rows, dtype, compiled=True)
+    with pytest.raises(ValueError, match=f"page_size % {rows} == 0"):
+        check_page_size(rows // 2, dtype, compiled=True)
+    check_page_size(rows // 2, dtype, compiled=False)
+    with pytest.raises(ValueError, match="page_size must be >= 1"):
+        check_page_size(0, dtype, compiled=False)
+
+
+@pytest.mark.parametrize("max_new,joins", [((3, 3, 3), 0), ((2, 5, 3), 1)])
+def test_joins_count_only_admissions_beside_a_live_slot(rng, max_new, joins):
+    """`stats["joins"]` counts a paged admission only when another slot is
+    still decoding. Two slots: with (3, 3, 3) both first requests finish in
+    the same round, so the third enters an idle engine (no join); with
+    (2, 5, 3) the third takes the first slot while the second decodes."""
+    cfg = reduced(get_config("olmo-1b"))
+    model = Model(cfg)
+    params = model.init(rng)
+    rng_np = np.random.default_rng(3)
+    reqs = [Request(i, rng_np.integers(0, cfg.vocab_size, 8).astype(np.int32),
+                    m) for i, m in enumerate(max_new)]
+    eng = ServeEngine(model, params, backend=get_backend("reference"),
+                      config=ServeConfig(batch_size=2, max_len=16,
+                                         cache="paged"))
+    done = eng.run(reqs)
+    assert sorted(len(r.out) for r in done) == sorted(max_new)
+    assert eng.stats["joins"] == joins, eng.stats
 
 
 def test_int8_auto_routes_paged(rng):
