@@ -34,6 +34,7 @@ from repro.core import annotation, baselines, increm, lr_head
 from repro.core.deltagrad import build_correction_schedule, deltagrad_replay
 from repro.core.influence import infl, influence_vector, top_b
 from repro.core.pipeline import train_head
+from repro.utils.timing import span
 
 
 class RoundSelection(NamedTuple):
@@ -67,17 +68,19 @@ class InflSelector:
 
     def select(self, session, eligible, key) -> RoundSelection:
         cfg, ds, bk = session.cfg, session.ds, session.backend
-        v, _ = influence_vector(
-            session.w, session.Xa_val, ds.y_val, session.Xa, ds.y_weight, cfg.l2,
-            cg_iters=cfg.cg_iters, cg_tol=cfg.cg_tol, backend=bk,
-        )
-        if self.mode.startswith("increm"):
-            priority, suggested, pruned = increm.increm_infl(
-                session.prov, session.w, v, session.Xa, ds.y_prob, cfg.gamma,
-                eligible, cfg.round_size, tight=(self.mode == "increm_tight"),
-                backend=bk,
+        with span("repro.chef.select.cg"):
+            v, _ = influence_vector(
+                session.w, session.Xa_val, ds.y_val, session.Xa, ds.y_weight,
+                cfg.l2, cg_iters=cfg.cg_iters, cg_tol=cfg.cg_tol, backend=bk,
             )
-            n_cand = int(pruned.n_candidates)
+        if self.mode.startswith("increm"):
+            with span("repro.chef.select.prune"):
+                priority, suggested, pruned = increm.increm_infl(
+                    session.prov, session.w, v, session.Xa, ds.y_prob,
+                    cfg.gamma, eligible, cfg.round_size,
+                    tight=(self.mode == "increm_tight"), backend=bk,
+                )
+                n_cand = int(pruned.n_candidates)
         else:
             r = infl(session.w, v, session.Xa, ds.y_prob, cfg.gamma, backend=bk)
             priority, suggested, n_cand = r.priority, r.suggested, ds.n
@@ -221,13 +224,16 @@ class DeltaGradConstructor:
     def construct(self, session, idx, labels) -> ConstructorResult:
         ds_old = session.ds
         ds_new = ds_old.clean(idx, labels)
-        ci, cm = build_correction_schedule(np.asarray(session.sched), np.asarray(idx))
-        w, traj = deltagrad_replay(
-            session.traj[0], session.traj[1], session.sched, session.Xa,
-            ds_old.y_prob, ds_new.y_prob, ds_old.y_weight, ds_new.y_weight,
-            ci, cm, session.dgc, int(session.sched.shape[1]),
-            backend=session.backend,
-        )
+        with span("repro.chef.update.schedule"):
+            ci, cm = build_correction_schedule(np.asarray(session.sched),
+                                               np.asarray(idx))
+        with span("repro.chef.update.replay"):
+            w, traj = deltagrad_replay(
+                session.traj[0], session.traj[1], session.sched, session.Xa,
+                ds_old.y_prob, ds_new.y_prob, ds_old.y_weight, ds_new.y_weight,
+                ci, cm, session.dgc, int(session.sched.shape[1]),
+                backend=session.backend,
+            )
         return ConstructorResult(ds_new, w, session.backend.shard_trajectory(traj),
                                  session.sched)
 

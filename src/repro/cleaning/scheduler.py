@@ -35,7 +35,6 @@ Early termination is first-class: `TargetF1`, `Patience`, and
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 from typing import NamedTuple, Optional, Protocol, Sequence, runtime_checkable
 
@@ -55,6 +54,7 @@ from repro.cleaning.phases import (
 from repro.cleaning.session import CleaningSession
 from repro.core.pipeline import ChefResult, RoundRecord, _evaluate
 from repro.dist.fault import Heartbeat, retry_step
+from repro.utils.timing import span
 
 
 # ------------------------------------------------------- termination policies
@@ -226,7 +226,8 @@ class RoundScheduler:
 
     # ------------------------------------------------------------- one round
     def step(self) -> RoundRecord:
-        return self._commit(self._compute())
+        with span("repro.chef.round", k=self.session.round):
+            return self._commit(self._compute())
 
     def _compute_round(self) -> _RoundOutcome:
         """Select / annotate / construct for the current round. Mutates NO
@@ -242,86 +243,97 @@ class RoundScheduler:
         if pf is not None and pf.round == k:
             selection, t_select = pf.selection, pf.t_select
         else:
-            t0 = time.perf_counter()
-            selection = self.selector.select(s, eligible, k_sel)
-            jax.block_until_ready(selection.idx)
-            t_select = time.perf_counter() - t0
+            selection, t_select = self._select(s, k, eligible, k_sel)
 
-        # ---- annotation phase (simulated-async: votes land after latency)
-        task = self.annotator.annotate(s, selection, k_vote)
+        # ---- annotation phase (simulated-async: votes land after latency;
+        # the speculation runs inside that wait)
+        with span("repro.chef.annotate"):
+            task = self.annotator.annotate(s, selection, k_vote)
 
-        spec: Optional[_Speculation] = None
-        if self.pipelined and not task.ready():
-            pred = self.annotator.predict(s, selection)
-            if pred is not None:
-                spec = self._speculate(k, selection, pred)
+            spec: Optional[_Speculation] = None
+            if self.pipelined and not task.ready():
+                pred = self.annotator.predict(s, selection)
+                if pred is not None:
+                    spec = self._speculate(k, selection, pred)
 
-        labels = task.result()
+            labels = task.result()
 
         # ---- model constructor phase (adopt speculation iff votes match)
         if spec is not None and bool(jnp.all(labels == spec.labels)):
             return _RoundOutcome(k, selection, t_select, spec.result,
                                  spec.t_update, "hit", spec.prefetch)
-        t1 = time.perf_counter()
-        result = self.constructor.construct(s, selection.idx, labels)
-        jax.block_until_ready(result.w)
-        t_update = time.perf_counter() - t1
+        result, t_update = self._construct(s, k, selection, labels)
         return _RoundOutcome(k, selection, t_select, result, t_update,
                              "miss" if spec is not None else None, None)
+
+    def _select(self, s, k: int, eligible, key):
+        """(selection, seconds): round k's selection, ended by
+        block_until_ready, in its `repro.chef.select` span."""
+        with span("repro.chef.select", k=k) as sp:
+            selection = self.selector.select(s, eligible, key)
+            jax.block_until_ready(selection.idx)
+        return selection, sp.seconds
+
+    def _construct(self, s, k: int, selection: RoundSelection, labels):
+        """(result, seconds): round k's model update on `labels`, ended by
+        block_until_ready, in its `repro.chef.update` span."""
+        with span("repro.chef.update", k=k) as sp:
+            result = self.constructor.construct(s, selection.idx, labels)
+            jax.block_until_ready(result.w)
+        return result, sp.seconds
 
     def _commit(self, o: _RoundOutcome) -> RoundRecord:
         """Apply one computed round: the only state-mutation point. Runs
         exactly once per round (outside the retry wrapper); a failure here
         propagates instead of silently re-running the round."""
-        s = self.session
-        self._prefetch = o.prefetch
-        if o.spec == "hit":
-            self.spec_hits += 1
-        elif o.spec == "miss":
-            self.spec_misses += 1
-        selection, result = o.selection, o.result
-        match = (
-            float(jnp.mean((selection.suggested[selection.idx]
-                            == s.ds.y_true[selection.idx]).astype(jnp.float32)))
-            if selection.suggested is not None else float("nan")
-        )
-        f1v, f1t = _evaluate(result.w, result.ds)
-        record = RoundRecord(o.round, int(jnp.sum(result.ds.cleaned)), f1v, f1t,
-                             selection.n_candidates, o.t_select, o.t_update, match)
-        s.apply_round(result.ds, result.w, result.traj, result.sched, record)
-        if any(p.should_stop(s.history) for p in self.termination):
-            s.terminated = True
-        if self.verbose:
-            print(
-                f"round {o.round}: cleaned={record.n_cleaned_total} "
-                f"f1_val={f1v:.4f} f1_test={f1t:.4f} cand={record.n_candidates} "
-                f"sel={o.t_select:.3f}s upd={o.t_update:.3f}s"
+        with span("repro.chef.commit"):
+            s = self.session
+            self._prefetch = o.prefetch
+            if o.spec == "hit":
+                self.spec_hits += 1
+            elif o.spec == "miss":
+                self.spec_misses += 1
+            selection, result = o.selection, o.result
+            match = (
+                float(jnp.mean((selection.suggested[selection.idx]
+                                == s.ds.y_true[selection.idx]).astype(jnp.float32)))
+                if selection.suggested is not None else float("nan")
             )
-        if self.heartbeat is not None:
-            self.heartbeat.beat(s.round)
-        if self.ckpt is not None and self.ckpt_every \
-                and s.round % self.ckpt_every == 0:
-            s.save(self.ckpt)
-        return record
+            f1v, f1t = _evaluate(result.w, result.ds)
+            record = RoundRecord(o.round, int(jnp.sum(result.ds.cleaned)), f1v,
+                                 f1t, selection.n_candidates, o.t_select,
+                                 o.t_update, match)
+            s.apply_round(result.ds, result.w, result.traj, result.sched, record)
+            if any(p.should_stop(s.history) for p in self.termination):
+                s.terminated = True
+            if self.verbose:
+                print(
+                    f"round {o.round}: cleaned={record.n_cleaned_total} "
+                    f"f1_val={f1v:.4f} f1_test={f1t:.4f} "
+                    f"cand={record.n_candidates} "
+                    f"sel={o.t_select:.3f}s upd={o.t_update:.3f}s"
+                )
+            if self.heartbeat is not None:
+                self.heartbeat.beat(s.round)
+            if self.ckpt is not None and self.ckpt_every \
+                    and s.round % self.ckpt_every == 0:
+                s.save(self.ckpt)
+            return record
 
     def _speculate(self, k: int, selection: RoundSelection, pred) -> _Speculation:
         """Run constructor + next-round selection on the predicted labels
         while the annotators are still voting. Pure w.r.t. the session."""
         s = self.session
-        t1 = time.perf_counter()
-        result = self.constructor.construct(s, selection.idx, pred)
-        jax.block_until_ready(result.w)
-        t_update = time.perf_counter() - t1
+        with span("repro.chef.speculate", k=k):
+            result, t_update = self._construct(s, k, selection, pred)
 
-        prefetch = None
-        # prefetch round k+1's scoring unless the budget already ends the run
-        if s.ledger.remaining >= 2 * s.cfg.round_size:
-            child = s.child(result.ds, result.w, result.traj, result.sched)
-            k_sel_next, _ = s.round_keys(k + 1)
-            t0 = time.perf_counter()
-            sel_next = self.selector.select(child, child.eligible(), k_sel_next)
-            jax.block_until_ready(sel_next.idx)
-            prefetch = _Prefetch(k + 1, sel_next, time.perf_counter() - t0)
+            prefetch = None
+            # prefetch round k+1's scoring unless the budget already ends the run
+            if s.ledger.remaining >= 2 * s.cfg.round_size:
+                child = s.child(result.ds, result.w, result.traj, result.sched)
+                k_sel_next, _ = s.round_keys(k + 1)
+                prefetch = _Prefetch(k + 1, *self._select(
+                    child, k + 1, child.eligible(), k_sel_next))
         return _Speculation(pred, result, t_update, prefetch)
 
 

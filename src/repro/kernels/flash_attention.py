@@ -136,6 +136,7 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(*position_blocks(qpos, kpos), q, k, v)
 
 

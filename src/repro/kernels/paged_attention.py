@@ -171,8 +171,10 @@ def _kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, *,
 
 
 def _page_partials_call(kernel, q, k_pages, v_pages, page_table, pos, *,
-                        interpret: bool, extra=(), extra_specs=()):
-    """Shared pallas_call plumbing of the two paged kernels.
+                        name: str, interpret: bool, extra=(),
+                        extra_specs=()):
+    """Shared pallas_call plumbing of the two paged kernels; `name` is the
+    kernel's name in the device trace.
 
     Grid (B, n_pages) with pages innermost: each step DMAs ONE physical
     page — all kv heads of it, a [P, Hkv, D] block whose tiled trailing
@@ -209,6 +211,7 @@ def _page_partials_call(kernel, q, k_pages, v_pages, page_table, pos, *,
             jax.ShapeDtypeStruct((B, n_pages, Hkv, G, D), jnp.float32),
         ],
         interpret=interpret,
+        name=name,
     )(page_table, pos, q, k_pages, v_pages, *extra)
     return (m.transpose(0, 2, 1, 3), l.transpose(0, 2, 1, 3),
             acc.transpose(0, 2, 1, 3, 4))
@@ -240,6 +243,7 @@ def paged_attention_partials_pallas(
         softcap=float(softcap), page_size=k_pages.shape[1],
     )
     return _page_partials_call(kernel, q, k_pages, v_pages, page_table, pos,
+                               name="paged_decode_attention",
                                interpret=interpret)
 
 
@@ -304,7 +308,8 @@ def paged_attention_partials_quant_pallas(
     )
     srow = pl.BlockSpec((1, 1, Hkv), lambda b, j, pt, ps: (pt[b, j], 0, 0))
     return _page_partials_call(
-        kernel, q, k_pages, v_pages, page_table, pos, interpret=interpret,
+        kernel, q, k_pages, v_pages, page_table, pos,
+        name="quant_paged_decode_attention", interpret=interpret,
         extra=(k_scale.reshape(NP, 1, Hkv), v_scale.reshape(NP, 1, Hkv)),
         extra_specs=(srow, srow))
 

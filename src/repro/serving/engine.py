@@ -117,6 +117,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.utils.timing import span
+
 
 def make_prefill_step(model, backend=None, cache_len=None):
     """Closure for jitting `model.prefill` (dry-run cells + the engine).
@@ -340,21 +342,22 @@ class ServeEngine:
 
     def run(self, requests: list) -> list:
         """Serve `requests` to completion; returns them in finish order."""
-        pending, done = [], []
-        for r in requests:
-            # a zero-budget request never enters a slot: in a wave it would
-            # be dropped from the results, and as a mid-stream join it would
-            # set remaining = -1 and spin the decode loop forever
-            if r.max_new <= 0:
-                r.done = True
-                done.append(r)
-            else:
-                pending.append(r)
-        if self.cache_mode == "paged":
-            if self.config.spec_k > 1:
-                return self._run_paged_spec(pending, done)
-            return self._run_paged(pending, done)
-        return self._run_ring(pending, done)
+        with span("repro.serve.run", n=len(requests)):
+            pending, done = [], []
+            for r in requests:
+                # a zero-budget request never enters a slot: in a wave it
+                # would be dropped from the results, and as a mid-stream join
+                # it would set remaining = -1 and spin the decode loop forever
+                if r.max_new <= 0:
+                    r.done = True
+                    done.append(r)
+                else:
+                    pending.append(r)
+            if self.cache_mode == "paged":
+                if self.config.spec_k > 1:
+                    return self._run_paged_spec(pending, done)
+                return self._run_paged(pending, done)
+            return self._run_ring(pending, done)
 
     # ------------------------------------------------------------- paged path
     def _bucket(self, n: int) -> int:
@@ -716,22 +719,23 @@ class ServeEngine:
                     f"{r.max_new} exceeds max_len {self.max_len}")
             if len(r.prompt) == 0:
                 raise ValueError(f"request {r.uid}: empty prompt")
-        if self.config.share_prefix and self._pool is not None:
-            # warm pool: the previous run() left every slot parked (trash
-            # row, pos 0) and its prefix-index pins still hold their pages —
-            # reuse the physical cache + free list so this wave's prompts
-            # alias pages prefilled by earlier waves. page_refs and
-            # _prefix_index carry over; only the work counters reset.
-            cache, free = self._pool
-            cache = self._sync_refcount(self._commit_cache(cache))
-        else:
-            cache = self._commit_cache(self.model.init_paged_cache(
-                self.B, self.num_pages, P, self.table_pages))
-            free = list(range(1, self.num_pages))  # page 0 = reserved trash
-            # fresh allocator state: host-authoritative page refcounts (page
-            # usable iff 0 == free, writable iff 1) and the prefix index
-            self.page_refs = np.zeros(self.num_pages, np.int32)
-            self._prefix_index = OrderedDict()
+        with span("repro.serve.pool_init"):
+            if self.config.share_prefix and self._pool is not None:
+                # warm pool: the previous run() left every slot parked (trash
+                # row, pos 0) and its prefix-index pins still hold their pages —
+                # reuse the physical cache + free list so this wave's prompts
+                # alias pages prefilled by earlier waves. page_refs and
+                # _prefix_index carry over; only the work counters reset.
+                cache, free = self._pool
+                cache = self._sync_refcount(self._commit_cache(cache))
+            else:
+                cache = self._commit_cache(self.model.init_paged_cache(
+                    self.B, self.num_pages, P, self.table_pages))
+                free = list(range(1, self.num_pages))  # page 0 = reserved trash
+                # fresh allocator state: host-authoritative page refcounts (page
+                # usable iff 0 == free, writable iff 1) and the prefix index
+                self.page_refs = np.zeros(self.num_pages, np.int32)
+                self._prefix_index = OrderedDict()
         slot_pages: list = [[] for _ in range(self.B)]
         active: list = [None] * self.B
         remaining = [0] * self.B
@@ -766,38 +770,43 @@ class ServeEngine:
         cache, nxt, free, slot_pages, active, remaining = self._paged_init(
             pending, done)
         while any(r is not None for r in active):
-            for i, r in enumerate(active):
-                if r is not None:  # CoW any still-shared write-target page
-                    cache = self._cow_guard(
-                        cache, free, slot_pages, i,
-                        len(r.prompt) + len(r.out) - 1)
-            self.stats["decode_rounds"] += 1
-            self.stats["slot_rounds"] += sum(r is not None for r in active)
-            logits, cache = self._decode(self.params, cache, {"tokens": nxt})
-            nxt = greedy(logits)
-            nxt_np = np.asarray(nxt)
-            log_np = (np.asarray(logits)
-                      if self.config.trace_logits else None)
-            freed = False
-            for i, r in enumerate(active):
-                if r is None:
-                    continue
-                r.out.append(int(nxt_np[i, 0]))
-                if log_np is not None:
-                    r.logits.append(log_np[i, 0].copy())
-                remaining[i] -= 1
-                if remaining[i] == 0:
-                    r.done = True
-                    done.append(r)
-                    active[i] = None
-                    cache = self._release_slot(cache, free, slot_pages, i)
-                    freed = True
-            cache, retired = self._retire_window_pages(cache, free,
-                                                       slot_pages, active)
-            if freed or retired:
-                cache, nxt = self._admit_idle_slots(pending, done, cache, nxt,
-                                                    active, remaining, free,
-                                                    slot_pages)
+            n_active = sum(r is not None for r in active)
+            with span("repro.serve.decode_round", active=n_active):
+                for i, r in enumerate(active):
+                    if r is not None:  # CoW a still-shared write-target page
+                        cache = self._cow_guard(
+                            cache, free, slot_pages, i,
+                            len(r.prompt) + len(r.out) - 1)
+                self.stats["decode_rounds"] += 1
+                self.stats["slot_rounds"] += n_active
+                logits, cache = self._decode(self.params, cache,
+                                             {"tokens": nxt})
+                nxt = greedy(logits)
+                nxt_np = np.asarray(nxt)
+                log_np = (np.asarray(logits)
+                          if self.config.trace_logits else None)
+                with span("repro.serve.emit"):
+                    freed = False
+                    for i, r in enumerate(active):
+                        if r is None:
+                            continue
+                        r.out.append(int(nxt_np[i, 0]))
+                        if log_np is not None:
+                            r.logits.append(log_np[i, 0].copy())
+                        remaining[i] -= 1
+                        if remaining[i] == 0:
+                            r.done = True
+                            done.append(r)
+                            active[i] = None
+                            cache = self._release_slot(cache, free,
+                                                       slot_pages, i)
+                            freed = True
+                    cache, retired = self._retire_window_pages(
+                        cache, free, slot_pages, active)
+                    if freed or retired:
+                        cache, nxt = self._admit_idle_slots(
+                            pending, done, cache, nxt, active, remaining,
+                            free, slot_pages)
         if pending:
             # cannot happen with the auto-sized pool (B full tables + trash
             # always admit an empty batch) — but a hand-shrunk num_pages
@@ -968,67 +977,75 @@ class ServeEngine:
             j, need, n_share, aliased = cand
             pending.remove(j)
             L = len(j.prompt)
-            pages = aliased + [free.pop() for _ in range(need - n_share)]
-            for pg in pages:
-                self.page_refs[pg] += 1
-            slot_pages[slot] = pages
-            row = np.zeros(self.table_pages, np.int32)
-            row[:need] = pages
-            self._slot_rows[slot] = row
-            # int8 pools: zero the FRESH pages' scale rows before any write
-            # so the recycled pages' stale running-max scales never alter
-            # this request's quantization (aliased prefix pages keep theirs)
-            cache = self._reset_page_scales(cache, pages[n_share:])
-            width = self._bucket(L)
-            j.entry_width = width
-            self.stats["prompt_tokens"] += L
-            # admitted after decoding began while another slot is still
-            # decoding: a mid-stream join
-            self.stats["joins"] += int(self.stats["decode_rounds"] > 0 and any(
-                a is not None for a in active))
-            if n_share:
-                Ls = n_share * P
-                tail_w = self._bucket(L - Ls)
-                self.prefill_widths.add(tail_w)
-                self.stats["prefill_tokens"] += tail_w
-                self.stats["prefix_hit_tokens"] += Ls
-                self.stats["prefix_hits"] += 1
-                toks = np.zeros((1, tail_w), np.int32)
-                toks[0, :L - Ls] = j.prompt[Ls:]  # RIGHT-pad the tail
-                logits, dense = self._get_tail_prefill(tail_w, n_share, width)(
-                    self.params, jnp.asarray(toks), cache, jnp.asarray(row),
-                    jnp.asarray([L - Ls - 1], jnp.int32))
-                cache = self._commit_cache(self._get_tail_commit(tail_w)(
-                    cache, dense, jnp.asarray(row),
-                    jnp.asarray(Ls, jnp.int32), jnp.asarray(L, jnp.int32)))
-            else:
-                self.prefill_widths.add(width)
-                self.stats["prefill_tokens"] += width
-                toks = np.zeros((1, width), np.int32)
-                toks[0, :L] = j.prompt  # RIGHT-pad: pads past the causal mask
-                logits, dense = self._get_paged_prefill(width)(
-                    self.params, jnp.asarray(toks),
-                    jnp.asarray([L - 1], jnp.int32))
-                cache = self._commit_cache(self._get_paged_commit(width)(
-                    cache, dense, jnp.asarray(row),
-                    jnp.asarray(L, jnp.int32)))
-            self._register_prefix(j.prompt, width, row, free)
-            cache["pages"] = cache["pages"].at[slot].set(jnp.asarray(row))
-            cache["pos"] = cache["pos"].at[slot].set(L)
-            cache = self._sync_refcount(cache)
-            first = greedy(logits)
-            j.out.append(int(np.asarray(first)[0, 0]))
-            if self.config.trace_logits:
-                j.logits.append(np.asarray(logits)[0, 0].copy())
-            if j.max_new == 1:  # drained on its own prefill; slot frees again
-                j.done = True
-                done.append(j)
-                cache = self._release_slot(cache, free, slot_pages, slot)
-                continue
-            nxt = nxt.at[slot].set(first[0])
-            active[slot] = j
-            remaining[slot] = j.max_new - 1
-            return cache, nxt
+            Ls = n_share * P  # prompt positions aliased from the index
+            width, prefill_w = self._bucket(L), self._bucket(L - Ls)
+            with span("repro.serve.admit", req=j.uid, width=prefill_w):
+                pages = aliased + [free.pop() for _ in range(need - n_share)]
+                for pg in pages:
+                    self.page_refs[pg] += 1
+                slot_pages[slot] = pages
+                row = np.zeros(self.table_pages, np.int32)
+                row[:need] = pages
+                self._slot_rows[slot] = row
+                # int8 pools: zero the FRESH pages' scale rows before any
+                # write so the recycled pages' stale running-max scales never
+                # alter this request's quantization (aliased prefix pages keep
+                # theirs)
+                cache = self._reset_page_scales(cache, pages[n_share:])
+                j.entry_width = width
+                self.stats["prompt_tokens"] += L
+                # admitted after decoding began while another slot is still
+                # decoding: a mid-stream join
+                self.stats["joins"] += int(
+                    self.stats["decode_rounds"] > 0
+                    and any(a is not None for a in active))
+                self.prefill_widths.add(prefill_w)
+                self.stats["prefill_tokens"] += prefill_w
+                toks = np.zeros((1, prefill_w), np.int32)
+                toks[0, :L - Ls] = j.prompt[Ls:]  # RIGHT-pad past the mask
+                if n_share:
+                    self.stats["prefix_hit_tokens"] += Ls
+                    self.stats["prefix_hits"] += 1
+                    with span("repro.serve.prefill"):
+                        logits, dense = self._get_tail_prefill(
+                            prefill_w, n_share, width)(
+                            self.params, jnp.asarray(toks), cache,
+                            jnp.asarray(row),
+                            jnp.asarray([L - Ls - 1], jnp.int32))
+                else:
+                    with span("repro.serve.prefill"):
+                        logits, dense = self._get_paged_prefill(width)(
+                            self.params, jnp.asarray(toks),
+                            jnp.asarray([L - 1], jnp.int32))
+                with span("repro.serve.commit"):
+                    if n_share:
+                        cache = self._get_tail_commit(prefill_w)(
+                            cache, dense, jnp.asarray(row),
+                            jnp.asarray(Ls, jnp.int32),
+                            jnp.asarray(L, jnp.int32))
+                    else:
+                        cache = self._get_paged_commit(width)(
+                            cache, dense, jnp.asarray(row),
+                            jnp.asarray(L, jnp.int32))
+                    cache = self._commit_cache(cache)
+                    self._register_prefix(j.prompt, width, row, free)
+                    cache["pages"] = cache["pages"].at[slot].set(
+                        jnp.asarray(row))
+                    cache["pos"] = cache["pos"].at[slot].set(L)
+                    cache = self._sync_refcount(cache)
+                first = greedy(logits)
+                j.out.append(int(np.asarray(first)[0, 0]))
+                if self.config.trace_logits:
+                    j.logits.append(np.asarray(logits)[0, 0].copy())
+                if j.max_new == 1:  # drained on its prefill; slot frees again
+                    j.done = True
+                    done.append(j)
+                    cache = self._release_slot(cache, free, slot_pages, slot)
+                    continue
+                nxt = nxt.at[slot].set(first[0])
+                active[slot] = j
+                remaining[slot] = j.max_new - 1
+                return cache, nxt
 
     # -------------------------------------------------------------- ring path
     def _try_join(self, pending: list, done: list, cache, nxt, active,

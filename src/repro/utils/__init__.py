@@ -9,7 +9,7 @@ from repro.utils.tree import (
     tree_size,
     tree_cast,
 )
-from repro.utils.timing import Timer, timed
+from repro.utils.timing import span
 from repro.utils.logging import get_logger
 
 __all__ = [
@@ -22,7 +22,6 @@ __all__ = [
     "tree_zeros_like",
     "tree_size",
     "tree_cast",
-    "Timer",
-    "timed",
+    "span",
     "get_logger",
 ]

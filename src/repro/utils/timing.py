@@ -1,42 +1,48 @@
-"""Wall-clock timing utilities for benchmarks (block_until_ready-aware)."""
+"""The program's spans, and wall-clock timing for the CPU micro-benchmarks.
+
+`span` is the one way the program marks a phase: a
+`jax.profiler.TraceAnnotation` (recorded on the device trace's clock only
+while a profile is being taken, ~1 us to enter and exit otherwise) that
+also times itself on the host clock. Every span name starts with
+``repro.``; its keyword args carry the identifiers that tie spans together
+(`k` round, `req` request uid, `width` prefill width, `active` occupied
+slots).
+"""
 from __future__ import annotations
 
 import time
-from contextlib import contextmanager
 
 import jax
 
+PREFIX = "repro."
 
-class Timer:
-    """Accumulating timer; `with timer: ...` adds to .total."""
 
-    def __init__(self, name: str = ""):
-        self.name = name
-        self.total = 0.0
-        self.count = 0
+class span:
+    """Context manager: a named profiler span around the block, whose
+    host-clock duration is `.seconds` after exit.
+
+        with span("repro.chef.select", k=3) as sp:
+            ...
+        t_select = sp.seconds
+    """
+
+    __slots__ = ("_annotation", "_t0", "seconds")
+
+    def __init__(self, name: str, **args):
+        if not name.startswith(PREFIX):
+            raise ValueError(f"span name {name!r} must start with {PREFIX!r}")
+        self._annotation = jax.profiler.TraceAnnotation(name, **args)
+        self.seconds = None
 
     def __enter__(self):
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self.total += time.perf_counter() - self._t0
-        self.count += 1
+        self.seconds = time.perf_counter() - self._t0
+        self._annotation.__exit__(*exc)
         return False
-
-    @property
-    def mean(self) -> float:
-        return self.total / max(self.count, 1)
-
-
-@contextmanager
-def timed(out: dict, key: str):
-    """Context manager that records elapsed seconds into out[key] (accumulating)."""
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        out[key] = out.get(key, 0.0) + (time.perf_counter() - t0)
 
 
 def time_fn(fn, *args, iters: int = 5, warmup: int = 2, **kwargs) -> float:

@@ -20,6 +20,7 @@ described device cannot be read back without one.
 from __future__ import annotations
 
 import os
+import re
 
 import pytest
 
@@ -170,12 +171,23 @@ CASES["paged_attention_int8"] = (
      ((B, S // _P8), i32), ((B,), i32)])
 
 
+# kernels whose pallas_call carries a name: the device trace shows the
+# custom call under it (not as the enclosing `%closed_call.N`)
+NAMED = {"flash_attention": "flash_attention",
+         "flash_attention_unaligned": "flash_attention",
+         "paged_attention": "paged_decode_attention",
+         "paged_attention_f32": "paged_decode_attention",
+         "paged_attention_int8": "quant_paged_decode_attention"}
+
+
 @pytest.mark.parametrize("kernel", sorted(CASES))
 def test_kernel_compiles_for_v5e(kernel, one_chip):
     fn, shapes = CASES[kernel]
     hlo = _compile(fn, shapes, one_chip)
     # the kernel really went through Mosaic (not an XLA fallback)
     assert "tpu_custom_call" in hlo
+    if kernel in NAMED:
+        assert re.search(rf"%{NAMED[kernel]}(\.\d+)? = .*custom-call\(", hlo)
 
 
 def test_page_tile_rows_follow_pool_dtype():
