@@ -62,15 +62,16 @@ def run(N: int = 8192, d: int = 2048, C: int = 2, backend: str = "all") -> list:
     t_ref = {}
     for name in names:
         bk = get_backend(name)
+        Xr = bk.row_gather_layout(Xa)
         # (op, fn, matmul-equivalents, rows the matmuls run over)
         pairs = [
             ("infl_scores", lambda: bk.infl_scores(v, Xa, P, Y, 0.8), 1, N),
             ("lr_grad", lambda: bk.lr_grad(w, Xa, Y, w8, 0.05), 2, N),
             ("lr_hvp", lambda: bk.lr_hvp(w, v, Xa, w8, 0.05), 2, N),
             ("minibatch_grad",
-             lambda: bk.minibatch_grad(w, Xa, Y, w8, idx, 0.05), 2, bs),
+             lambda: bk.minibatch_grad(w, Xr, Y, w8, idx, 0.05), 2, bs),
             ("replay_correction",
-             lambda: bk.replay_correction(w, Xa, Y, Y_new, w8, w8_new,
+             lambda: bk.replay_correction(w, Xr, Y, Y_new, w8, w8_new,
                                           ci, cm, bs), 2, r),
         ]
         for op, fn, matmuls, n_rows in pairs:

@@ -190,14 +190,15 @@ def round1_inputs(session):
 def clean_ops(bk, a):
     """Every kernel-backed op of the cleaning loop on one backend."""
     cfg = a["cfg"]
+    Xr = bk.row_gather_layout(a["Xa"])
     return {
         "scores": bk.probs_scores(a["w"], a["v"], a["Xa"], a["Y"], cfg.gamma),
         "lr_grad": bk.lr_grad(a["w"], a["Xa"], a["Y"], a["wt"], cfg.l2),
         "lr_hvp": bk.lr_hvp(a["w"], a["v"], a["Xa"], a["wt"], cfg.l2),
-        "minibatch_grad": bk.minibatch_grad(a["w"], a["Xa"], a["Y"], a["wt"],
+        "minibatch_grad": bk.minibatch_grad(a["w"], Xr, a["Y"], a["wt"],
                                             a["idx"], cfg.l2),
         "replay_correction": bk.replay_correction(
-            a["w"], a["Xa"], a["Y"], a["y_new"], a["wt"], a["ones"], a["ci"],
+            a["w"], Xr, a["Y"], a["y_new"], a["wt"], a["ones"], a["ci"],
             a["cm"], cfg.batch_size),
     }
 
@@ -239,9 +240,10 @@ def assert_clean_compiled(session) -> None:
                     gamma=cfg.gamma)
     assert_compiled("lr_grad", ops.lr_grad, s.w, s.Xa, Y, wt, l2=cfg.l2)
     assert_compiled("lr_hvp", ops.lr_hvp, s.w, s.w, s.Xa, wt, l2=cfg.l2)
-    assert_compiled("minibatch_grad", ops.minibatch_grad, s.w, s.Xa, Y, wt,
+    Xr = ops.row_gather_layout(s.Xa)
+    assert_compiled("minibatch_grad", ops.minibatch_grad, s.w, Xr, Y, wt,
                     s.sched[0], l2=cfg.l2)
-    assert_compiled("replay_correction", ops.replay_correction, s.w, s.Xa, Y,
+    assert_compiled("replay_correction", ops.replay_correction, s.w, Xr, Y,
                     Y, wt, wt, s.sched[0][:16], wt[:16],
                     batch_size=cfg.batch_size)
 

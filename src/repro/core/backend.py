@@ -33,12 +33,17 @@ computation inside `lr_head.sgd_train` and `deltagrad.deltagrad_replay`
 dispatches through these, mirroring how the selector phase dispatches the
 four scoring ops above):
 
-  minibatch_grad(w, Xa, Y, weights, idx, l2)             -> [C, d+1]
+  row_gather_layout(Xa)                                  -> Xr
+      the array both ops below gather batch rows from: Xa itself on
+      reference, the kernels' [N, 1, Dp] view on pallas, Xa with its rows
+      padded to the data axes on pallas_sharded. A copy of Xa, so the SGD
+      and replay scans lay out once before the loop, not per step.
+  minibatch_grad(w, Xr, Y, weights, idx, l2)             -> [C, d+1]
       gathered mini-batch gradient over B_t = Xa[idx] (Eq. 4 left term):
       one fused gather+softmax+grad kernel on pallas; on pallas_sharded
       Xa/Y/weights stay row-sharded and ONLY the gathered [bs, d+1] batch
       rows are all-gathered (masked local take + psum) per step.
-  replay_correction(w, Xa, Y_old, Y_new, w_old, w_new,
+  replay_correction(w, Xr, Y_old, Y_new, w_old, w_new,
                     corr_idx, corr_mask, batch_size)     -> [C, d+1]
       fused DeltaGrad correction over the changed slots of B_t (Eq. 4
       right term, Section 4.2): one shared softmax feeds both the old- and
@@ -239,55 +244,67 @@ class Backend:
         return _cached_sharded(self, "probs_scores", float(gamma))(w, v, Xp, Yp)[:n]
 
     # ------------------------------------------------- constructor-phase ops
-    def minibatch_grad(self, w, Xa, Y, weights, idx, l2: float) -> jax.Array:
+    def row_gather_layout(self, Xa) -> jax.Array:
+        """Xa laid out for `minibatch_grad` / `replay_correction` (see the
+        module docstring). Exact: zero lanes or zero rows only."""
+        if self.name == "reference":
+            return Xa
+        from repro.kernels import ops
+
+        if self.name == "pallas":
+            return ops.row_gather_layout(Xa)
+        _, dp, lead = self._data_axes()
+        if lead is None:
+            return ops.row_gather_layout(Xa)
+        # each shard gathers from its 2-D rows; the psum'd batch is laid
+        # out inside the shard_map (bs rows, not N)
+        return ops._pad_rows(Xa, dp)[0]
+
+    def minibatch_grad(self, w, Xr, Y, weights, idx, l2: float) -> jax.Array:
         """Gathered mini-batch gradient over B_t = Xa[idx] (Eq. 4 left term):
         the SGD-scan step of `sgd_train` and DeltaGrad-L's explicit
-        iterations. Bit-identical across backends (see module docstring)."""
+        iterations, with Xr = `row_gather_layout(Xa)`. Bit-identical across
+        backends (see module docstring)."""
         if self.name == "reference":
             from repro.core import lr_head
 
-            return lr_head.minibatch_grad_reference(w, Xa, Y, weights, idx, l2)
-        if self.name == "pallas":
-            from repro.kernels import ops
-
-            return ops.minibatch_grad(w, Xa, Y, weights, idx, l2)
+            return lr_head.minibatch_grad_reference(w, Xr, Y, weights, idx, l2)
         from repro.kernels import ops
-        from repro.kernels.ops import _pad_rows
 
+        if self.name == "pallas":
+            return ops.minibatch_grad(w, Xr, Y, weights, idx, l2)
         _, dp, lead = self._data_axes()
         if lead is None:
-            return ops.minibatch_grad(w, Xa, Y, weights, idx, l2)
-        Xp, Yp, w8p = (_pad_rows(a, dp)[0] for a in (Xa, Y, weights))
+            return ops.minibatch_grad(w, Xr, Y, weights, idx, l2)
+        Yp, w8p = (ops._pad_rows(a, dp)[0] for a in (Y, weights))
         return _cached_sharded(self, "minibatch_grad", float(l2))(
-            w, idx.astype(jnp.int32), Xp, Yp, w8p)
+            w, idx.astype(jnp.int32), Xr, Yp, w8p)
 
-    def replay_correction(self, w, Xa, Y_old, Y_new, w_old, w_new,
+    def replay_correction(self, w, Xr, Y_old, Y_new, w_old, w_new,
                           corr_idx, corr_mask, batch_size: int) -> jax.Array:
         """Fused DeltaGrad-L replay correction over the changed slots of B_t
-        (Eq. 4 right term): padded slots (corr_mask == 0) contribute exactly
-        zero. Bit-identical across backends."""
+        (Eq. 4 right term), with Xr = `row_gather_layout(Xa)`: padded slots
+        (corr_mask == 0) contribute exactly zero. Bit-identical across
+        backends."""
         if self.name == "reference":
             from repro.core import deltagrad
 
             return deltagrad.replay_correction_reference(
-                w, Xa, Y_old, Y_new, w_old, w_new, corr_idx, corr_mask,
+                w, Xr, Y_old, Y_new, w_old, w_new, corr_idx, corr_mask,
                 batch_size)
-        if self.name == "pallas":
-            from repro.kernels import ops
-
-            return ops.replay_correction(w, Xa, Y_old, Y_new, w_old, w_new,
-                                         corr_idx, corr_mask, batch_size)
         from repro.kernels import ops
-        from repro.kernels.ops import _pad_rows
 
+        if self.name == "pallas":
+            return ops.replay_correction(w, Xr, Y_old, Y_new, w_old, w_new,
+                                         corr_idx, corr_mask, batch_size)
         _, dp, lead = self._data_axes()
         if lead is None:
-            return ops.replay_correction(w, Xa, Y_old, Y_new, w_old, w_new,
+            return ops.replay_correction(w, Xr, Y_old, Y_new, w_old, w_new,
                                          corr_idx, corr_mask, batch_size)
-        Xp, Yop, Ynp, wop, wnp = (
-            _pad_rows(a, dp)[0] for a in (Xa, Y_old, Y_new, w_old, w_new))
+        Yop, Ynp, wop, wnp = (
+            ops._pad_rows(a, dp)[0] for a in (Y_old, Y_new, w_old, w_new))
         return _cached_sharded(self, "replay_correction", float(batch_size))(
-            w, corr_idx.astype(jnp.int32), corr_mask, Xp, Yop, Ynp, wop, wnp)
+            w, corr_idx.astype(jnp.int32), corr_mask, Xr, Yop, Ynp, wop, wnp)
 
     # ---------------------------------------------------------- serving ops
     def _model_axis_divides(self, n_kv_heads: int) -> bool:
@@ -834,7 +851,7 @@ class Backend:
                 # gather is the identity here (the batch is already
                 # assembled), so the fused kernel's take() is exact
                 return ops.minibatch_grad(
-                    ww, xb, yb, wb,
+                    ww, ops.row_gather_layout(xb), yb, wb,
                     jnp.arange(idxg.shape[0], dtype=jnp.int32), static)
 
             return shard_map_compat(
@@ -845,7 +862,7 @@ class Backend:
                 xb, yo, yn, wo, wn = _gather_rows_psum(
                     (xs, yos, yns, wos, wns), ci, ba)
                 return ops.replay_correction(
-                    ww, xb, yo, yn, wo, wn,
+                    ww, ops.row_gather_layout(xb), yo, yn, wo, wn,
                     jnp.arange(ci.shape[0], dtype=jnp.int32), cm, int(static))
 
             return shard_map_compat(

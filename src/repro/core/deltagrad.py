@@ -165,8 +165,11 @@ def deltagrad_replay(
 
     Constructor-phase dispatch: the explicit-iteration batch gradients and
     the per-iteration corrections go through `Backend.minibatch_grad` /
-    `Backend.replay_correction` (bit-identical across the three backends).
-    On pallas_sharded, Xa/Y stay row-sharded, only the gathered batch rows
+    `Backend.replay_correction` (bit-identical across the three backends),
+    both gathering from Xa laid out once here, before the scan
+    (`Backend.row_gather_layout`: a copy of Xa, loop-invariant, which XLA
+    would not hoist out of the loop itself). On pallas_sharded, Xa/Y stay
+    row-sharded, only the gathered batch rows
     are all-gathered per step, the replayed [T, C, d+1] trajectory is
     constrained row-sharded over the data axes, and the L-BFGS (ΔW, ΔG)
     ring buffers are pinned replicated."""
@@ -178,12 +181,14 @@ def deltagrad_replay(
     t_arr = jnp.arange(T)
     explicit = (t_arr < cfg.burn_in) | (((t_arr - cfg.burn_in) % cfg.period) == 0)
 
+    Xr = bk.row_gather_layout(Xa)
+
     def batch_grad(w, idx):
-        return bk.minibatch_grad(w, Xa, Y_old, w_old, idx, cfg.l2)
+        return bk.minibatch_grad(w, Xr, Y_old, w_old, idx, cfg.l2)
 
     def correction(w, ci, cm):
         """(1/|B|) Σ_changed [ 1·∇F(w, z_new) − γ·∇F(w, z_old) ]."""
-        return bk.replay_correction(w, Xa, Y_old, Y_new, w_old, w_new,
+        return bk.replay_correction(w, Xr, Y_old, Y_new, w_old, w_new,
                                     ci, cm, batch_size)
 
     def step(carry, xs):

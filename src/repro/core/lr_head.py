@@ -180,14 +180,16 @@ def sgd_train(
     `Backend` (constructor-phase mirror of the scoring dispatch): reference
     jnp, fused Pallas gather+grad kernel, or the shard_map path where
     Xa/Y/weights stay row-sharded and only the gathered [bs, d+1] batch is
-    all-gathered per step. All three produce bit-identical weights and
-    trajectories. On pallas_sharded the cached [T, C, d+1] trajectory is
+    all-gathered per step; Xa is laid out for the gather once, before the
+    scan. All three produce bit-identical weights and trajectories. On
+    pallas_sharded the cached [T, C, d+1] trajectory is
     constrained row-sharded over the mesh's data axes."""
     bk = get_backend(backend)
+    Xr = bk.row_gather_layout(Xa)
 
     def step(carry, idx):
         w, mom = carry
-        g = bk.minibatch_grad(w, Xa, Y, weights, idx, l2)
+        g = bk.minibatch_grad(w, Xr, Y, weights, idx, l2)
         mom_new = momentum * mom + g if momentum else mom
         w_new = w - lr * (mom_new if momentum else g)
         out = (w, g) if cache_trajectory else None

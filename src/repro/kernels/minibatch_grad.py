@@ -10,10 +10,11 @@ rows of Xa itself and runs the logits matmul -> masked softmax -> weighted
 residual -> gradient matmul epilogue on them, so the gathered [bs, d+1]
 batch never round-trips through HBM between the gather and the two MXU dots.
 
-Row gather: Xa stays in HBM (`memory_space=pl.ANY`) as an [N, 1, D] view —
-one row per leading index, so a single row is a whole (1, D) tile and one
-DMA moves it. The batch ids are scalar-prefetched into SMEM; each grid step
-issues one row DMA per batch slot of its `block_b`-row chunk into a VMEM
+Row gather: Xa stays in HBM (`memory_space=pl.ANY`) as an [N, 1, D] view
+(`ops.row_gather_layout`, built once per scan by the caller) — one row per
+leading index, so a single row is a whole (1, D) tile and one DMA moves it.
+The batch ids are scalar-prefetched into SMEM; each grid step issues one
+row DMA per batch slot of its `block_b`-row chunk into a VMEM
 scratch, waits for them, and folds the chunk's contribution into the
 resident [C, D] output. Only the batch rows ever enter VMEM, whatever N is.
 The labels and weights of the batch ([bs, C] and [bs], tiny) arrive already
@@ -92,10 +93,10 @@ def _kernel(idx_ref, x_hbm, y_ref, w8_ref, w_ref, o_ref, xb, sem, *,
 
 def minibatch_grad_pallas(
     w: jax.Array,  # [C, D]
-    Xa: jax.Array,  # [N, D]
+    Xr: jax.Array,  # [N, 1, D] row-gather view of Xa
     yb: jax.Array,  # [bs, C] labels of the batch rows (already gathered)
     wb: jax.Array,  # [bs] weights of the batch rows (already gathered)
-    idx: jax.Array,  # [bs] int32 row ids into Xa
+    idx: jax.Array,  # [bs] int32 row ids into Xr
     l2: float,
     *,
     n_batch: int | None = None,
@@ -127,7 +128,7 @@ def minibatch_grad_pallas(
             pl.BlockSpec((C, D), lambda c, ids: (0, 0)),
         ],
         out_specs=pl.BlockSpec((C, D), lambda c, ids: (0, 0)),
-        scratch_shapes=[pltpu.VMEM((bb, 1, D), Xa.dtype),
+        scratch_shapes=[pltpu.VMEM((bb, 1, D), Xr.dtype),
                         pltpu.SemaphoreType.DMA((1,))],
     )
     return pl.pallas_call(
@@ -135,4 +136,4 @@ def minibatch_grad_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((C, D), jnp.float32),
         interpret=interpret,
-    )(idx, Xa.reshape(Xa.shape[0], 1, D), yb, wb.reshape(bs, 1), w)
+    )(idx, Xr, yb, wb.reshape(bs, 1), w)

@@ -149,47 +149,61 @@ def lr_hvp(w, v, Xa, weights, l2: float, P=None):
 GATHER_BLOCK = 256
 
 
+@jax.jit
+def row_gather_layout(Xa):
+    """Xa [N, D] -> the [N, 1, Dp] view the row-gather kernels
+    (`minibatch_grad`, `replay_correction`) DMA batch rows from: one row
+    per leading index, so one row is one whole (1, Dp) tile. On TPU the
+    lanes pad with zeros to a multiple of 128; interpret mode keeps D.
+    Zero lanes only, no reordering (exact). Laying out is a copy of all of
+    Xa, so a scan over steps lays out once before the loop and passes the
+    result to every step."""
+    Xp = Xa if _interpret() else _pad_dim(Xa, 1, 128)
+    return Xp.reshape(Xp.shape[0], 1, Xp.shape[1])
+
+
 @functools.partial(jax.jit, static_argnames=("l2",))
-def minibatch_grad(w, Xa, Y, weights, idx, l2: float):
+def minibatch_grad(w, Xr, Y, weights, idx, l2: float):
     """Fused gather + mini-batch gradient (constructor-phase hot op).
 
-    The kernel DMAs the Xa batch rows itself; the batch's labels and
-    weights are gathered here (tiny). Interpret mode runs the kernel
-    UNPADDED in one chunk: the body is then the same floating-point program
-    as the reference scan step, which is what makes
-    sgd_train/deltagrad_replay bit-identical across backends. On TPU, lanes
-    pad to 128 and the batch pads to `GATHER_BLOCK`-row chunks whose padded
-    slots point at row 0 with weight 0 (exact-zero contribution)."""
+    `Xr` is `row_gather_layout(Xa)`. The kernel DMAs the batch rows itself;
+    the batch's labels and weights are gathered here (tiny). Interpret mode
+    runs the kernel UNPADDED in one chunk: the body is then the same
+    floating-point program as the reference scan step, which is what makes
+    sgd_train/deltagrad_replay bit-identical across backends. On TPU, w
+    pads to the layout's lanes and the batch pads to `GATHER_BLOCK`-row
+    chunks whose padded slots point at row 0 with weight 0 (exact-zero
+    contribution)."""
     idx = idx.astype(jnp.int32)
     yb, wb = Y[idx], weights[idx]
     if _interpret():
-        return minibatch_grad_pallas(w, Xa, yb, wb, idx, l2, interpret=True)
-    C = w.shape[0]
+        return minibatch_grad_pallas(w, Xr, yb, wb, idx, l2, interpret=True)
+    C, D = w.shape
     bs = idx.shape[0]
     lane = 128
     bb = min(GATHER_BLOCK, -(-bs // 8) * 8)
     pad = (-bs) % bb
     wp = _pad_dim(_pad_dim(w, 0, lane), 1, lane)
     g = minibatch_grad_pallas(
-        wp, _pad_dim(Xa, 1, lane),
-        jnp.pad(_pad_dim(yb, 1, lane), ((0, pad), (0, 0))),
+        wp, Xr, jnp.pad(_pad_dim(yb, 1, lane), ((0, pad), (0, 0))),
         jnp.pad(wb, (0, pad)), jnp.pad(idx, (0, pad)), l2,
         n_batch=bs, c_actual=C, block_b=bb, interpret=False)
-    return g[:C, : Xa.shape[1]]
+    return g[:C, :D]
 
 
 @functools.partial(jax.jit, static_argnames=("batch_size",))
-def replay_correction(w, Xa, Y_old, Y_new, w_old, w_new, ci, cm,
+def replay_correction(w, Xr, Y_old, Y_new, w_old, w_new, ci, cm,
                       batch_size: int):
-    """Fused gather + DeltaGrad-L replay correction. Same interpret-unpadded
-    bit-parity contract as `minibatch_grad`; TPU padding extends ci with
-    pointers to row 0 and cm with zeros (exact-zero contribution)."""
+    """Fused gather + DeltaGrad-L replay correction over `Xr =
+    row_gather_layout(Xa)`. Same interpret-unpadded bit-parity contract as
+    `minibatch_grad`; TPU padding extends ci with pointers to row 0 and cm
+    with zeros (exact-zero contribution)."""
     ci = ci.astype(jnp.int32)
     yo, yn, wo, wn = Y_old[ci], Y_new[ci], w_old[ci], w_new[ci]
     if _interpret():
-        return replay_correction_pallas(w, Xa, yo, yn, wo, wn, ci, cm,
+        return replay_correction_pallas(w, Xr, yo, yn, wo, wn, ci, cm,
                                         batch_size, interpret=True)
-    C = w.shape[0]
+    C, D = w.shape
     lane = 128
     pad = (-ci.shape[0]) % 8
 
@@ -197,11 +211,11 @@ def replay_correction(w, Xa, Y_old, Y_new, w_old, w_new, ci, cm,
         return jnp.pad(a, [(0, pad)] + [(0, 0)] * (a.ndim - 1))
 
     g = replay_correction_pallas(
-        _pad_dim(_pad_dim(w, 0, lane), 1, lane), _pad_dim(Xa, 1, lane),
+        _pad_dim(_pad_dim(w, 0, lane), 1, lane), Xr,
         rows(_pad_dim(yo, 1, lane)), rows(_pad_dim(yn, 1, lane)),
         rows(wo), rows(wn), rows(ci), rows(cm), batch_size, c_actual=C,
         interpret=False)
-    return g[:C, : Xa.shape[1]]
+    return g[:C, :D]
 
 
 def _attn_blocks(Sq: int, Skv: int) -> tuple:

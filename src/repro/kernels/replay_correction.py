@@ -49,7 +49,7 @@ def _kernel(ci_ref, x_hbm, yo_ref, yn_ref, wo_ref, wn_ref, cm_ref, w_ref,
 
 def replay_correction_pallas(
     w: jax.Array,  # [C, D]
-    Xa: jax.Array,  # [N, D]
+    Xr: jax.Array,  # [N, 1, D] row-gather view of Xa
     yo: jax.Array,  # [r, C] old probabilistic labels of the slots
     yn: jax.Array,  # [r, C] cleaned labels of the slots
     wo: jax.Array,  # [r] old per-sample weights (γ) of the slots
@@ -79,7 +79,7 @@ def replay_correction_pallas(
             pl.BlockSpec((C, D), lambda i, ids: (0, 0)),
         ],
         out_specs=pl.BlockSpec((C, D), lambda i, ids: (0, 0)),
-        scratch_shapes=[pltpu.VMEM((r, 1, D), Xa.dtype),
+        scratch_shapes=[pltpu.VMEM((r, 1, D), Xr.dtype),
                         pltpu.SemaphoreType.DMA((1,))],
     )
     return pl.pallas_call(
@@ -87,5 +87,5 @@ def replay_correction_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((C, D), jnp.float32),
         interpret=interpret,
-    )(ci, Xa.reshape(Xa.shape[0], 1, D), yo, yn, wo.reshape(r, 1),
+    )(ci, Xr, yo, yn, wo.reshape(r, 1),
       wn.reshape(r, 1), cm.reshape(r, 1), w)

@@ -56,6 +56,40 @@ def test_lr_hvp(N, D, C, dtype, rng):
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=tol, rtol=1e-2)
 
 
+@pytest.mark.parametrize("N,D,C", SHAPES)
+@pytest.mark.parametrize("op", ["minibatch_grad", "replay_correction"])
+def test_row_gather_op_bitwise(op, N, D, C, rng, monkeypatch):
+    """The row-gather wrappers fed `row_gather_layout(Xa)` are bit-identical
+    to the jitted reference functions on the plain Xa (the constructor
+    parity contract: both run compiled, as inside the SGD and replay
+    scans), and the layout is Xa itself plus zero lanes: unpadded in
+    interpret mode, padded to 128 lanes on the compiled path."""
+    from repro.core import deltagrad, lr_head
+
+    X, Y, P, w, v, w8 = _data(rng, N, D, C, jnp.float32)
+    idx = jax.random.randint(jax.random.fold_in(rng, N + D), (48,), 0, N)
+    Xr = ops.row_gather_layout(X)
+    np.testing.assert_array_equal(np.asarray(Xr), np.asarray(X)[:, None, :])
+    if op == "minibatch_grad":
+        got = ops.minibatch_grad(w, Xr, Y, w8, idx, 0.05)
+        want = jax.jit(lr_head.minibatch_grad_reference,
+                       static_argnames="l2")(w, X, Y, w8, idx, l2=0.05)
+    else:
+        ci, cm = idx[:7], jnp.ones((7,)).at[5:].set(0.0)  # 2 padded slots
+        ones = jnp.ones((N,))
+        got = ops.replay_correction(w, Xr, Y, P, w8, ones, ci, cm, 48)
+        want = jax.jit(deltagrad.replay_correction_reference,
+                       static_argnames="batch_size")(
+            w, X, Y, P, w8, ones, ci, cm, batch_size=48)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # the compiled path's layout, traced unjitted so no cache keeps it
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    Xp = np.asarray(ops.row_gather_layout.__wrapped__(X))
+    assert Xp.shape == (N, 1, -(-D // 128) * 128)
+    np.testing.assert_array_equal(Xp[:, 0, :D], np.asarray(X))
+    assert not Xp[:, :, D:].any()
+
+
 @pytest.mark.parametrize(
     "B,Hq,Hkv,Sq,Skv,D,causal,window",
     [

@@ -8,6 +8,8 @@ it. Shapes are the ones the compiled path runs:
 
   * the five CHEF kernels at MIMIC's Table-3 size, N = 78,487 rows of
     d + 1 = 2,049 features, lane-padded to 2,176, classes padded to 128;
+  * the DeltaGrad-L replay and the SGD scan at the same size (T = 390
+    steps of a 2,000-row batch), whose loops must not copy Xa;
   * the serving kernels at olmo-1b's published widths: 16 heads of 128,
     bf16 activations, a 2,048-token context.
 
@@ -117,12 +119,12 @@ CASES = {
     "minibatch_grad": (
         lambda w, X, y, g, i: minibatch_grad_pallas(
             w, X, y, g, i, 0.05, n_batch=BATCH, c_actual=2, block_b=BB),
-        [((LANE, DP), f32), ((N, DP), f32), ((NB, LANE), f32), ((NB,), f32),
-         ((NB,), i32)]),
+        [((LANE, DP), f32), ((N, 1, DP), f32), ((NB, LANE), f32),
+         ((NB,), f32), ((NB,), i32)]),
     "replay_correction": (
         lambda w, X, yo, yn, wo, wn, ci, cm: replay_correction_pallas(
             w, X, yo, yn, wo, wn, ci, cm, BATCH, c_actual=2),
-        [((LANE, DP), f32), ((N, DP), f32), ((16, LANE), f32),
+        [((LANE, DP), f32), ((N, 1, DP), f32), ((16, LANE), f32),
          ((16, LANE), f32), ((16,), f32), ((16,), f32), ((16,), i32),
          ((16,), f32)]),
     "flash_attention": (
@@ -192,3 +194,98 @@ def test_kernel_compiles_for_v5e(kernel, one_chip):
 
 def test_page_tile_rows_follow_pool_dtype():
     assert [page_tile_rows(d) for d in (f32, bf16, i8)] == [8, 16, 32]
+
+
+# HLO text: a computation's header, and an instruction's name, result type
+# and opcode (a tuple type has no space before its opcode's parenthesis)
+_COMP = re.compile(r"^(?:ENTRY )?%([\w.\-]+) ")
+_INSTR = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([a-z][\w\-]*)\(")
+_CALLS = re.compile(r"(?:calls|to_apply|body|condition|true_computation|"
+                    r"false_computation)=%([\w.\-]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+# ops that pass an array along without writing it
+_PASS = {"parameter", "get-tuple-element", "tuple", "bitcast"}
+
+
+def _computations(hlo: str) -> dict:
+    """{computation: [(name, result type, opcode, line)]} of an HLO text."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        m = _COMP.match(line)
+        if m and line.rstrip().endswith("{"):
+            cur = comps.setdefault(m.group(1), [])
+            continue
+        m = _INSTR.match(line)
+        if m and cur is not None:
+            cur.append((m.group(1), m.group(2), m.group(3), line))
+    return comps
+
+
+def _callees(comps: dict, roots) -> set:
+    """`roots` and every computation they call, transitively."""
+    seen, todo = set(), list(roots)
+    while todo:
+        c = todo.pop()
+        if c in seen:
+            continue
+        seen.add(c)
+        for *_, line in comps.get(c, []):
+            todo += _CALLS.findall(line)
+            for group in _BRANCHES.findall(line):
+                todo += [b.strip().lstrip("%") for b in group.split(",")]
+    return seen
+
+
+def _scan(name: str):
+    """(step function, argument shapes, kernels in its loop) of one of the
+    two constructor scans on pallas at MIMIC size: T = 390 steps (10
+    epochs) of a 2,000-row batch, r_max 8 corrections a replay step."""
+    from repro.core import lr_head
+    from repro.core.backend import get_backend
+    from repro.core.deltagrad import DGConfig, deltagrad_replay
+
+    T, R, C = 390, 8, 2
+    D = MIMIC.feature_dim + 1
+    bk = get_backend("pallas")
+    if name == "sgd_train":
+        return (lambda *a: lr_head.sgd_train(*a, l2=0.05, lr=0.0005,
+                                             backend=bk),
+                [((C, D), f32), ((N, D), f32), ((N, C), f32), ((N,), f32),
+                 ((T, BATCH), i32)], ("minibatch_grad",))
+    return (lambda *a: deltagrad_replay(*a, DGConfig(), BATCH, backend=bk),
+            [((T, C, D), f32), ((T, C, D), f32), ((T, BATCH), i32),
+             ((N, D), f32), ((N, C), f32), ((N, C), f32), ((N,), f32),
+             ((N,), f32), ((T, R), i32), ((T, R), f32)],
+            ("minibatch_grad", "replay_correction"))
+
+
+@pytest.mark.parametrize("scan", ["deltagrad_replay", "sgd_train"])
+def test_scan_lays_out_xa_once_for_v5e(scan, one_chip, monkeypatch):
+    """The DeltaGrad-L replay and the SGD scan on pallas at MIMIC size,
+    compiled for v5e: the row-gather layout of Xa ([N, 1, 2,176]) is built
+    once, before the scan, and no op in the scan's loop writes an array of
+    Xa's rows (a pad, reshape or copy there would copy Xa on each of the T
+    steps). `ops._interpret` is forced off: this process's backend is the
+    CPU, and the compiled path is the one under test."""
+    fn, shapes, kernels = _scan(scan)
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    try:
+        hlo = _compile(fn, shapes, one_chip)
+    finally:
+        jax.clear_caches()  # drop the compiled-path traces
+    comps = _computations(hlo)
+    loops = [(m.group(1), m.group(2)) for m in re.finditer(
+        r"condition=%([\w.\-]+), body=%([\w.\-]+)", hlo)]
+    assert loops, "the scan compiled to no while loop"
+    in_loop = _callees(comps, [c for pair in loops for c in pair])
+    written = [line.strip()[:160] for c in in_loop for _, ty, op, line in
+               comps[c] if ty.startswith(f"f32[{N},") and op not in _PASS]
+    assert not written, written
+    layout = [op for c, ins in comps.items() if c not in in_loop
+              for _, ty, op, _ in ins
+              if ty.startswith(f"f32[{N},1,{DP}]") and op not in _PASS]
+    assert len(layout) == 1, layout
+    # the kernels run in the loop, on the laid-out array
+    for kernel in kernels:
+        assert any(name.startswith(kernel) and op == "custom-call"
+                   for c in in_loop for name, _, op, _ in comps[c]), kernel
